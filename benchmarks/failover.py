@@ -72,6 +72,8 @@ def main(argv=None) -> None:
     p.add_argument("--json-path", default=BENCH_JSON, metavar="PATH",
                    help="ledger path (default: repo BENCH_netsim.json)")
     args = p.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     rows = [run_variant(name, algo, rec)

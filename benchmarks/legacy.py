@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.core import registry, reps
 from repro.core.types import CCEvent
+from repro.kernels.enqueue_arb import ops as enqueue_arb_ops
 from repro.netsim import engine, fabric, faults, metrics, sender
 from repro.netsim.metrics import HIST_BINS
 from repro.netsim.state import pkt_size
@@ -332,13 +333,14 @@ def build_legacy(cfg, wl):
     import dataclasses
     sim = engine.build(cfg, wl)
     cc_update = registry.get(cfg.algo, cfg.cc_backend)
+    _, arb = enqueue_arb_ops.get(cfg.fabric_backend)
     dims, consts = sim.dims, sim.consts
 
     def step(st):
         st = _departures(dims, consts, st)
         st = _arrivals(dims, consts, st)
         st = _control(dims, consts, cc_update, st)
-        st = sender.grants(dims, consts, st)
+        st = sender.grants(dims, consts, st, arb=arb)
         st = _sends(dims, consts, st)
         st = metrics.account(dims, consts, st)
         return st._replace(now=st.now + 1)

@@ -241,6 +241,8 @@ def main(argv=None) -> None:
                    help="comma-separated scenario-name filter (applies to "
                         "the dense, leap, and three-tier lists)")
     args = p.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     reps = args.reps or (2 if args.quick else 4)
     only = set(args.only.split(",")) if args.only else None
 

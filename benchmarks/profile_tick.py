@@ -162,6 +162,8 @@ def main(argv=None) -> None:
     p.add_argument("--reps", type=int, default=3)
     p.add_argument("--json-path", default=BENCH_JSON, metavar="PATH")
     args = p.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.ns:
         ns = [int(x) for x in args.ns.split(",") if x]
     else:

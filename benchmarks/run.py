@@ -43,7 +43,7 @@ def _row_dicts(rows, errors):
     return out
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("figs", nargs="*", help="substring filters (fig2 fig6 ...)")
     p.add_argument("--json", action="store_true",
@@ -58,6 +58,8 @@ def main(argv=None) -> None:
                         "(benchmarks.sweep) and record their StudyResult "
                         "rows (section 'studies')")
     args = p.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     t0 = time.time()
     from benchmarks import fig_benchmarks as F
@@ -77,7 +79,8 @@ def main(argv=None) -> None:
                   and "quick" in inspect.signature(fn).parameters else {})
             rows.extend(fn(**kw))
         except Exception as e:  # noqa: BLE001
-            # keep the CSV row shape but never swallow the diagnosis
+            # keep the CSV row shape but never swallow the diagnosis:
+            # the traceback goes to stderr and the exit code is non-zero
             traceback.print_exc(file=sys.stderr)
             errors.append((fn.__name__, f"{type(e).__name__}:{e}"))
             print(f"{fn.__name__},0,ERROR:{type(e).__name__}:{e}")
@@ -110,7 +113,12 @@ def main(argv=None) -> None:
         roofline.main()
 
     print(f"\n# total wall: {time.time()-t0:.1f}s; {len(rows)} rows")
+    if errors:
+        print(f"# {len(errors)} figure(s) failed: "
+              f"{', '.join(name for name, _ in errors)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -1,18 +1,23 @@
-"""Fleet-scale Study throughput: lanes/sec vs forced host-device count,
-plus cold/warm result-cache wall time (DESIGN.md Sec. 7).
+"""Fleet-scale Study throughput: lanes/sec against the number of devices
+the lanes are sharded over, plus cold/warm result-cache wall time
+(DESIGN.md Sec. 7).
 
-Device count is fixed at process start (XLA reads
-``--xla_force_host_platform_device_count`` before the first jax import),
-so every measurement runs in a *worker subprocess* launched with its own
-``XLA_FLAGS``; the parent only orchestrates and writes the ledger.
+The parent never imports JAX: it starts ONE worker process and writes
+the ledger.  The worker builds a lane mesh over ``jax.devices()[:d]``
+for each requested ``d`` (capped at the devices it sees) and then runs
+the cache measurement, all in that one process, so one process holds the
+chip.  The worker is started with
+``--xla_force_host_platform_device_count=max(d)``: on the CPU that gives
+it ``max(d)`` host devices to shard over; the flag touches only the CPU
+backend, so on a TPU the worker shards over the chips it sees.
 
 Two row families land in ``BENCH_netsim.json`` under
-``sections.study_throughput``:
+``sections.study_throughput``, each naming the device it ran on:
 
 - ``<scenario>/d<D>``: one Study (base point x S seeds) sharded over D
-  forced host devices — steady-state (post-compile) wall, lanes/sec, and
-  the full final-state pytree digest.  The parent *hard-fails* unless
-  every D produces the same digest as D=1: bit-identical sharding is an
+  devices — steady-state (post-compile) wall, lanes/sec, and the full
+  final-state pytree digest.  The parent *hard-fails* unless every D
+  produces the same digest as the first: bit-identical sharding is an
   acceptance property, not a perf number.
 - ``<scenario>/cache/{cold,warm}``: the same Study run against a fresh
   content-addressed cache (cold: every lane computed + written back)
@@ -42,39 +47,42 @@ _MARK = "STUDY_THROUGHPUT_RESULT "
 
 
 # --------------------------------------------------------------------------
-# worker side (runs with XLA_FLAGS already set by the parent)
+# worker side (the one process that touches JAX)
 # --------------------------------------------------------------------------
 
 
-def _worker_shard(scenario: str, n_seeds: int) -> dict:
+def _worker(scenario: str, n_seeds: int, devices: list) -> dict:
     import jax
 
+    from repro.compile_cache import use_compile_cache
     from repro.netsim import api, cache, shard
 
-    n_dev = jax.device_count()
+    use_compile_cache()
+    devs = jax.devices()
     st = api.study(scenario, seeds=tuple(range(n_seeds)))
-    mesh = shard.lane_mesh() if n_dev > 1 else None
-    first = st.run(mesh=mesh)           # compile + run
-    steady = st.run(mesh=mesh)          # reuses the jit cache
-    return dict(
-        devices=n_dev, lanes=st.n_lanes,
-        wall_first_s=round(first.wall_s, 4),
-        wall_s=round(steady.wall_s, 4),
-        lanes_per_sec=round(st.n_lanes / steady.wall_s, 3),
-        digest=cache.state_digest(steady.states),
-    )
+    shard_rows = []
+    for d in sorted({min(d, len(devs)) for d in devices}):
+        mesh = shard.lane_mesh(devs[:d]) if d > 1 else None
+        first = st.run(mesh=mesh)           # compile + run
+        steady = st.run(mesh=mesh)          # reuses the jit cache
+        shard_rows.append(dict(
+            devices=d, lanes=st.n_lanes,
+            wall_first_s=round(first.wall_s, 4),
+            wall_s=round(steady.wall_s, 4),
+            lanes_per_sec=round(st.n_lanes / steady.wall_s, 3),
+            digest=cache.state_digest(steady.states)))
 
-
-def _worker_cache(scenario: str, n_seeds: int) -> dict:
-    from repro.netsim import api, cache
-
-    st = api.study(scenario, seeds=tuple(range(n_seeds)))
     root = tempfile.mkdtemp(prefix="netsim_cache_bench_")
     try:
         rc = cache.ResultCache(root)
         cold = st.run(cache=rc)
         warm = st.run(cache=rc)
-        return dict(
+    finally:
+        shutil.rmtree(root, ignore_errors=True)
+    return dict(
+        platform=devs[0].platform, device_kind=devs[0].device_kind,
+        shard=shard_rows,
+        cache=dict(
             lanes=st.n_lanes,
             cold_wall_s=round(cold.wall_s, 4),
             warm_wall_s=round(warm.wall_s, 4),
@@ -82,33 +90,29 @@ def _worker_cache(scenario: str, n_seeds: int) -> dict:
             warm_hits=warm.cache_hits, warm_misses=warm.cache_misses,
             speedup=round(cold.wall_s / max(warm.wall_s, 1e-9), 2),
             cold_digest=cache.state_digest(cold.states),
-            warm_digest=cache.state_digest(warm.states),
-        )
-    finally:
-        shutil.rmtree(root, ignore_errors=True)
+            warm_digest=cache.state_digest(warm.states)))
 
 
-def _run_worker(mode: str, scenario: str, n_seeds: int,
-                devices: int = 1) -> dict:
-    """Launch one measurement subprocess with its own device count and
-    parse its ``STUDY_THROUGHPUT_RESULT`` line."""
+def _run_worker(scenario: str, n_seeds: int, devices: list) -> dict:
+    """Launch the measurement process and parse its
+    ``STUDY_THROUGHPUT_RESULT`` line."""
     env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={devices}"
-                        ).strip()
+                        " --xla_force_host_platform_device_count="
+                        f"{max(devices)}").strip()
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(REPO_ROOT, "src"),
                     env.get("PYTHONPATH", "")) if p)
     cmd = [sys.executable, "-m", "benchmarks.study_throughput", "--worker",
-           mode, "--scenario", scenario, "--seeds", str(n_seeds)]
+           "--scenario", scenario, "--seeds", str(n_seeds),
+           "--devices", ",".join(map(str, devices))]
     proc = subprocess.run(cmd, cwd=REPO_ROOT, env=env, text=True,
                           capture_output=True, timeout=3600)
     for line in reversed(proc.stdout.splitlines()):
         if line.startswith(_MARK):
             return json.loads(line[len(_MARK):])
     raise RuntimeError(
-        f"worker ({mode}, d={devices}) produced no result line\n"
+        f"worker produced no result line (exit {proc.returncode})\n"
         f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
 
 
@@ -124,49 +128,49 @@ def main(argv=None) -> int:
     p.add_argument("--scenario", default=None)
     p.add_argument("--seeds", type=int, default=None)
     p.add_argument("--devices", default=None,
-                   help="comma-separated forced host-device counts")
+                   help="comma-separated device counts")
     p.add_argument("--json-path", default=None)
-    p.add_argument("--worker", default=None, choices=("shard", "cache"),
-                   help=argparse.SUPPRESS)
+    p.add_argument("--worker", action="store_true", help=argparse.SUPPRESS)
     args = p.parse_args(argv)
 
     scenario = args.scenario or ("tiny_3t" if args.quick else "perm_512n_3t")
     n_seeds = args.seeds or (3 if args.quick else 8)
-
-    if args.worker:
-        fn = _worker_shard if args.worker == "shard" else _worker_cache
-        print(_MARK + json.dumps(fn(scenario, n_seeds)))
-        return 0
-
-    from benchmarks.common import emit, write_bench_json
-
     devices = ([int(d) for d in args.devices.split(",")] if args.devices
                else ([1, 2] if args.quick else [1, 2, 4, 8]))
-    rows = []
-    t0 = time.time()
 
+    if args.worker:
+        print(_MARK + json.dumps(_worker(scenario, n_seeds, devices)))
+        return 0
+
+    t0 = time.time()
+    w = _run_worker(scenario, n_seeds, devices)
+    # imported only now: benchmarks.common imports JAX, and the parent
+    # must not touch it while the worker holds the device
+    from benchmarks.common import emit, write_bench_json
+
+    where = dict(platform=w["platform"], device_kind=w["device_kind"])
+    rows = []
     base_digest = None
-    for d in devices:
-        r = _run_worker("shard", scenario, n_seeds, devices=d)
-        name = f"{scenario}/d{d}"
-        rows.append(dict(name=name, scenario=scenario, devices=r["devices"],
-                         lanes=r["lanes"], wall_s=r["wall_s"],
-                         wall_first_s=r["wall_first_s"],
+    for r in w["shard"]:
+        name = f"{scenario}/d{r['devices']}"
+        rows.append(dict(name=name, scenario=scenario, **where,
+                         devices=r["devices"], lanes=r["lanes"],
+                         wall_s=r["wall_s"], wall_first_s=r["wall_first_s"],
                          lanes_per_sec=r["lanes_per_sec"],
                          digest=r["digest"]))
-        emit(name, r["wall_s"],
-             f"{r['lanes_per_sec']:.2f} lanes/s on {r['devices']} dev")
+        emit(name, r["wall_s"], f"{r['lanes_per_sec']:.2f} lanes/s on "
+             f"{r['devices']} {w['device_kind']}")
         if base_digest is None:
             base_digest = r["digest"]
         elif r["digest"] != base_digest:
             print(f"::error title=shard parity::{name} final-state digest "
-                  f"{r['digest'][:12]} != d{devices[0]} "
+                  f"{r['digest'][:12]} != {rows[0]['name']} "
                   f"{base_digest[:12]} — sharded run is NOT bit-identical")
             raise SystemExit(1)
-    print(f"# shard parity: {len(devices)} device counts, one digest "
+    print(f"# shard parity: {len(w['shard'])} device counts, one digest "
           f"{base_digest[:12]}…")
 
-    c = _run_worker("cache", scenario, n_seeds, devices=1)
+    c = w["cache"]
     if c["cold_digest"] != c["warm_digest"] or \
             c["cold_digest"] != base_digest:
         print("::error title=cache parity::cold/warm digests diverge from "
@@ -177,12 +181,12 @@ def main(argv=None) -> int:
               f"{c['warm_misses']} lane(s); expected 0")
         raise SystemExit(1)
     rows.append(dict(name=f"{scenario}/cache/cold", scenario=scenario,
-                     lanes=c["lanes"], wall_s=c["cold_wall_s"],
+                     **where, lanes=c["lanes"], wall_s=c["cold_wall_s"],
                      lanes_per_sec=round(c["lanes"] / c["cold_wall_s"], 3),
                      cache_hits=c["cold_hits"],
                      cache_misses=c["cold_misses"]))
     rows.append(dict(name=f"{scenario}/cache/warm", scenario=scenario,
-                     lanes=c["lanes"], wall_s=c["warm_wall_s"],
+                     **where, lanes=c["lanes"], wall_s=c["warm_wall_s"],
                      lanes_per_sec=round(c["lanes"] / c["warm_wall_s"], 3),
                      cache_hits=c["warm_hits"],
                      cache_misses=c["warm_misses"],
@@ -197,8 +201,9 @@ def main(argv=None) -> int:
 
     path = write_bench_json("study_throughput", rows, path=args.json_path,
                             meta=dict(scenario=scenario, seeds=n_seeds,
-                                      note="workers forced device counts "
-                                           "via XLA_FLAGS"))
+                                      **where,
+                                      note="one worker process; lanes "
+                                           "sharded over jax.devices()[:d]"))
     print(f"# wrote {len(rows)} rows to {path} in {time.time()-t0:.0f}s")
     return 0
 

@@ -84,6 +84,8 @@ def main(argv=None) -> None:
     p.add_argument("--json-path", default=None, metavar="PATH",
                    help="ledger path (implies --json)")
     args = p.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.quick:
         scenarios_, algos = ("incast8_16n",), ("smartt", "eqds")
         grid, seeds, max_ticks = GRID[:4], (0,), MAX_TICKS // 4

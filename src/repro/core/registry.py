@@ -7,9 +7,8 @@ recompiles.
 Backends:
   ``jnp``    — the pure-jnp reference update (every algorithm).
   ``pallas`` — the blocked ``kernels/cc_update`` Pallas kernel streaming
-               the flow table through VMEM tiles (SMaRTT only; interpret
-               mode off-TPU, so it runs — and bit-matches the jnp backend —
-               everywhere).
+               the flow table through VMEM tiles (SMaRTT only; compiled on
+               a TPU, interpret mode elsewhere — ``kernels.interpret_mode``).
 """
 
 from __future__ import annotations
@@ -38,12 +37,10 @@ BACKENDS = ("jnp", "pallas")
 
 def _smartt_pallas_update(p, s, ev, now):
     # deferred import: keeps core importable without the kernels package
-    import jax
-
+    from repro.kernels import interpret_mode
     from repro.kernels.cc_update.ops import smartt_update_pallas
 
-    return smartt_update_pallas(
-        p, s, ev, now, interpret=jax.default_backend() != "tpu")
+    return smartt_update_pallas(p, s, ev, now, interpret=interpret_mode())
 
 
 PALLAS_ALGORITHMS = {

@@ -36,7 +36,7 @@ def quick_adapt(p: CCParams, s: CCState, unacked, now, gate):
     cwnd = jnp.where(fire, jnp.maximum(s.acked, p.mtu) * p.qa_scaling, s.cwnd)
     bytes_to_ignore = jnp.where(fire, unacked, s.bytes_to_ignore)
     bytes_ignored = jnp.where(fire, 0.0, s.bytes_ignored)
-    trigger_qa = jnp.where(fire, False, s.trigger_qa)
+    trigger_qa = s.trigger_qa & ~fire
     qa_end = jnp.where(boundary, now + p.trtt, s.qa_end)
     acked = jnp.where(boundary, 0.0, s.acked)
     s = s._replace(
@@ -56,7 +56,8 @@ def fast_increase(p: CCParams, s: CCState, ecn, rtt, size, gate):
     count = jnp.where(near_base, s.fi_count + size, 0.0)
     active = near_base & ((count > s.cwnd) | s.fi_active)
     cwnd = jnp.where(active, s.cwnd + p.k_fast * p.mtu, s.cwnd)
-    fi_active = jnp.where(gate, active, s.fi_active)
+    # boolean selects as and/or: Mosaic refuses a select between i1 vectors
+    fi_active = (gate & active) | (~gate & s.fi_active)
     fi_count = jnp.where(gate, count, s.fi_count)
     return s._replace(cwnd=cwnd, fi_active=fi_active, fi_count=fi_count), active
 

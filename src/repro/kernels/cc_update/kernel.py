@@ -5,7 +5,8 @@ This is the NIC datapath of the paper (Sec. 1.1.3: one packet every 40 ns at
 natural analogue is a struct-of-arrays sweep over the flow table: flow state
 lives in HBM as (F/128, 128)-shaped f32/i32 planes, the kernel streams
 (8, 128) VMEM tiles through the VPU, applying the entire Alg. 1-3 update as
-a branchless vector program.
+a branchless vector program.  The scalar parameters and the tick sit in
+SMEM.
 
 The arithmetic is *shared* with the engine: the kernel body calls
 ``repro.core.smartt.smartt_update`` on VMEM-resident tiles, so kernel and
@@ -20,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.cc_update import ref as R
 
@@ -35,6 +37,8 @@ N_EVENT_I32 = len(R.EVENT_I32)
 
 def _kernel(param_ref, now_ref, brtt_ref, trtt_ref, mi_ref,
             *refs):
+    # param_ref / now_ref live in SMEM: the oracle reads the parameter
+    # vector one scalar at a time (``param_vec[i]``), which a ref does too
     sf = [refs[i][...] for i in range(N_STATE_F32)]
     off = N_STATE_F32
     si = [refs[off + i][...] for i in range(N_STATE_I32)]
@@ -46,10 +50,9 @@ def _kernel(param_ref, now_ref, brtt_ref, trtt_ref, mi_ref,
     out_f = refs[off:off + N_STATE_F32]
     out_i = refs[off + N_STATE_F32:]
 
-    pvec = param_ref[0, :]
-    now = now_ref[0, 0]
     f32s, i32s = R.cc_update_ref(
-        pvec, brtt_ref[...], trtt_ref[...], mi_ref[...], now, sf, si, ef, ei)
+        param_ref, brtt_ref[...], trtt_ref[...], mi_ref[...], now_ref[0],
+        sf, si, ef, ei)
     for dst, val in zip(out_f, f32s):
         dst[...] = val
     for dst, val in zip(out_i, i32s):
@@ -59,7 +62,7 @@ def _kernel(param_ref, now_ref, brtt_ref, trtt_ref, mi_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def cc_update(param_vec, now, brtt, trtt, mi,
               state_f32s, state_i32s, event_f32s, event_i32s,
-              *, interpret: bool = True):
+              *, interpret: bool):
     """Blocked SMaRTT update over the flow table.
 
     Args:
@@ -88,8 +91,7 @@ def cc_update(param_vec, now, brtt, trtt, mi,
 
     grid = (rows_pad // BLOCK_ROWS,)
     tile = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
-    scalar_spec = pl.BlockSpec((1, param_vec.shape[0]), lambda i: (0, 0))
-    now_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     out_shapes = (
         [jax.ShapeDtypeStruct((rows_pad, LANES), jnp.float32)] * N_STATE_F32
@@ -98,12 +100,12 @@ def cc_update(param_vec, now, brtt, trtt, mi,
     outs = pl.pallas_call(
         _kernel,
         grid=grid,
-        in_specs=[scalar_spec, now_spec] + [tile] * (3 + len(ins)),
+        in_specs=[smem, smem] + [tile] * (3 + len(ins)),
         out_specs=[tile] * len(out_shapes),
         out_shape=out_shapes,
         interpret=interpret,
-    )(param_vec.reshape(1, -1).astype(jnp.float32),
-      jnp.asarray(now, jnp.float32).reshape(1, 1),
+    )(param_vec.astype(jnp.float32),
+      jnp.asarray(now, jnp.float32).reshape(1),
       brtt2, trtt2, mi2, *ins)
 
     def unshape(x):

@@ -1,8 +1,8 @@
 """Jit'd high-level wrapper: CCState/CCEvent pytrees -> cc_update kernel.
 
 Drop-in replacement for ``repro.core.smartt.smartt_update`` (SMaRTT fields
-only) running through the Pallas kernel.  ``interpret=True`` executes the
-kernel body on CPU for validation; on a TPU runtime pass interpret=False.
+only) running through the Pallas kernel.  ``interpret`` comes from
+``repro.kernels.interpret_mode`` (the interpreter off-TPU, Mosaic on it).
 """
 
 from __future__ import annotations
@@ -20,7 +20,7 @@ def pack_params(p: CCParams) -> jnp.ndarray:
 
 
 def smartt_update_pallas(p: CCParams, s: CCState, ev: CCEvent, now,
-                         *, interpret: bool = True) -> CCState:
+                         *, interpret: bool) -> CCState:
     F = s.cwnd.shape[0]
     brtt = jnp.broadcast_to(p.brtt, (F,)).astype(jnp.float32)
     trtt = jnp.broadcast_to(p.trtt, (F,)).astype(jnp.float32)

@@ -54,7 +54,7 @@ def _enqueue_kernel(gdst_ref, ghead_ref, gsize_ref,
 @functools.partial(jax.jit,
                    static_argnames=("cap", "nq", "interpret"))
 def enqueue_rank(gdst, ghead, gsize, *, cap: int, nq: int,
-                 interpret: bool = True):
+                 interpret: bool):
     """Blocked enqueue-rank over the switch fan-in groups.
 
     Args: i32 [S, D] per-slot destination queue / queue head / queue
@@ -78,17 +78,13 @@ def enqueue_rank(gdst, ghead, gsize, *, cap: int, nq: int,
 
 
 def _rr_kernel(elig_ref, rr_ref, has_ref, sel_ref, *, kmax: int):
-    elig = elig_ref[...] != 0
-    rr = rr_ref[...][:, 0]
-    has, sel = R.rr_pick_ref(elig, rr, kmax=kmax)
-    lanes = elig.shape[-1]
-    has_ref[...] = jnp.broadcast_to(has.astype(I32)[:, None],
-                                    (elig.shape[0], lanes))
-    sel_ref[...] = jnp.broadcast_to(sel[:, None], (elig.shape[0], lanes))
+    has, sel = R.rr_pick_tile(elig_ref[...] != 0, rr_ref[:, 0:1], kmax)
+    has_ref[...] = jnp.broadcast_to(has.astype(I32), has_ref.shape)
+    sel_ref[...] = jnp.broadcast_to(sel, sel_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("kmax", "interpret"))
-def rr_pick(elig, rr, *, kmax: int, interpret: bool = True):
+def rr_pick(elig, rr, *, kmax: int, interpret: bool):
     """Blocked round-robin argmin over [N, K] eligibility rows.
 
     Returns ``(has, sel)`` as bool[N] / i32[N] (see ``ref.rr_pick_ref``).

@@ -21,17 +21,17 @@ and ``sender.grants``/``sends``):
       Per-row round-robin argmin (see ``ref.rr_pick_ref``).
 
 Both backends are bit-for-bit interchangeable (asserted engine-deep in
-tests/test_engine_pallas.py); ``pallas`` runs in interpret mode off-TPU,
-exactly like the ``cc_update`` registry entry.
+tests/test_engine_pallas.py); ``pallas`` compiles through Mosaic on a
+TPU and runs in interpret mode elsewhere (``kernels.interpret_mode``).
 """
 
 from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.enqueue_arb import kernel as K
 from repro.kernels.enqueue_arb import ref as R
 
@@ -41,7 +41,7 @@ BACKENDS = ("jnp", "pallas")
 
 
 def enqueue_rank(in_tbl, in_pos, sw_of_q, edst, q_head, q_size, cap: int,
-                 nq: int, *, backend: str = "jnp", interpret: bool = True):
+                 nq: int, *, backend: str, interpret: bool):
     """Acceptance + queue position for every emitter's enqueue attempt.
 
     ``edst`` is i32 [EQ] over the compact enqueue-capable emitters
@@ -70,8 +70,7 @@ def enqueue_rank(in_tbl, in_pos, sw_of_q, edst, q_head, q_size, cap: int,
     return acc_g.reshape(-1)[in_pos], pos.reshape(-1)[in_pos], q_counts
 
 
-def rr_pick(elig, rr, kmax: int, *, backend: str = "jnp",
-            interpret: bool = True):
+def rr_pick(elig, rr, kmax: int, *, backend: str, interpret: bool):
     """Round-robin argmin per row — see ``ref.rr_pick_ref``."""
     if backend == "pallas":
         return K.rr_pick(elig, rr, kmax=kmax, interpret=interpret)
@@ -83,7 +82,7 @@ def get(backend: str):
     if backend not in BACKENDS:
         raise KeyError(
             f"unknown fabric backend {backend!r}; have {BACKENDS}")
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     return (functools.partial(enqueue_rank, backend=backend,
                               interpret=interpret),
             functools.partial(rr_pick, backend=backend,

@@ -18,10 +18,11 @@ Two vector programs the tick runs every cycle at fabric scale:
     Per-row round-robin argmin arbitration (sender flow pick, EQDS grant
     pick): smallest (slot - rr) mod K among eligible slots.  Padded slots
     must be ineligible; they then take the same key as ineligible real
-    slots (K + 1) at higher indices, so the first-min argmin — and the
+    slots (K + 1) at higher indices, so the first-min pick — and the
     no-candidate fallback index 0 — are unchanged by padding.
 
-The Pallas kernel bodies call these functions on VMEM-resident tiles, so
+The Pallas kernel bodies call ``enqueue_rank_ref`` and ``rr_pick_tile``
+(``rr_pick_ref`` on ``[N, 1]`` columns) on VMEM-resident tiles, so
 kernel and reference cannot drift apart (the ``kernels/cc_update``
 contract, DESIGN.md Sec. 6).
 """
@@ -70,9 +71,21 @@ def rr_pick_ref(elig, rr, kmax: int):
     Returns ``(has, sel)``: any-eligible flag and the picked slot index
     (0 where nothing is eligible — the caller gates on ``has``).
     """
+    has, sel = rr_pick_tile(elig, rr[..., None], kmax)
+    return has[..., 0], sel[..., 0]
+
+
+def rr_pick_tile(elig, rr, kmax: int):
+    """:func:`rr_pick_ref` with ``rr`` and both results as ``[..., 1]``
+    columns (the Pallas kernel's tile layout).
+
+    The first-min argmin is a min reduce and then the lowest slot holding
+    that minimum (Mosaic has no integer argmin).  Eligible keys lie in
+    ``[0, kmax)`` and the rest are ``kmax + 1``, so "any eligible" is
+    "the minimum key is below ``kmax``"."""
     k = elig.shape[-1]
-    keys = (jnp.arange(k, dtype=I32) - rr[..., None]) % kmax
-    keys = jnp.where(elig, keys, kmax + 1)
-    sel = jnp.argmin(keys, axis=-1)
-    has = jnp.any(elig, axis=-1)
-    return has, sel.astype(I32)
+    slot = jnp.arange(k, dtype=I32)
+    keys = jnp.where(elig, (slot - rr) % kmax, kmax + 1)
+    kmin = jnp.min(keys, axis=-1, keepdims=True)
+    sel = jnp.min(jnp.where(keys == kmin, slot, k), axis=-1, keepdims=True)
+    return kmin < kmax, sel

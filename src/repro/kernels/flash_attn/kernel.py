@@ -64,7 +64,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, sk, block_k, causal, window, sca
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: [B, H, Sq, D]; k/v: [B, H, Sk, D] (kv heads pre-broadcast).
     Sq % block_q == 0 and Sk % block_k == 0 required (pad upstream)."""
     b, h, sq, d = q.shape

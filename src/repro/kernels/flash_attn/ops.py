@@ -4,12 +4,12 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attn.kernel import flash_attention
 
 
 def gqa_flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                        block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
+                        block_q: int = 128, block_k: int = 128):
     """q: [B, Hq, Sq, D]; k/v: [B, Hkv, Sk, D] with Hq % Hkv == 0."""
     hq, hkv = q.shape[1], k.shape[1]
     if hq != hkv:
@@ -25,4 +25,4 @@ def gqa_flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         bk //= 2
     return flash_attention(q, k, v, causal=causal, window=window,
                            block_q=max(bq, 1), block_k=max(bk, 1),
-                           interpret=interpret)
+                           interpret=interpret_mode())

@@ -40,7 +40,7 @@ def _kernel(scal_ref, qsz_ref, arr_ref, qidx_ref, mark_ref, admit_ref, trim_ref)
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def red_mark(q_size, arrivals, cap, kmin, kmax, tick, salt, *,
-             interpret: bool = True):
+             interpret: bool):
     """Blocked RED marking over all port queues.  Shapes: i32[Q] -> i32[Q]x3."""
     Q = q_size.shape[0]
     rows = max(1, -(-Q // LANES))

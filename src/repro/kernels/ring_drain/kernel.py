@@ -3,10 +3,11 @@
 Phase 3's hot loop as a blocked vector program: the [NF, W] sent-ring
 planes stream through VMEM in (8, W-padded) tiles together with one
 [8, 128] lane-packed per-flow scalar tile each for the i32 event inputs
-(has_ack / ack_seq / started) and the f32 timeout threshold; the whole
-free/lose/timeout cascade plus the per-flow reductions happen on-tile.
-The kernel body calls the shared jnp reference (``ref.py``) on the VMEM
-tiles — the ``kernels/cc_update`` discipline — so kernel and oracle cannot
+(has_ack / ack_seq / started) and the f32 timeout threshold; the tick
+``t`` sits in SMEM.  The whole free/lose/timeout cascade plus the
+per-flow reductions happen on-tile, on 2-D values only.  The kernel body
+calls the shared jnp reference (``ref.drain_tile``) on the VMEM tiles —
+the ``kernels/cc_update`` discipline — so kernel and oracle cannot
 drift apart.  Padded rows/lanes hold zeros, which the reference leaves
 inert (a zero state is never freed, lost, or timed out).
 """
@@ -18,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ring_drain import ref as R
 
@@ -36,26 +38,23 @@ def _pad2(x, rows_pad: int, cols_pad: int):
 def _kernel(t_ref, scal_i_ref, scal_f_ref, lbits_ref, bitmap_ref,
             s0_ref, s1_ref, s2_ref, state_ref, counts_ref,
             *, w: int, ww: int, maxw: int):
-    t = t_ref[0, 0]
     si = scal_i_ref[...]
-    has_ack = si[:, 0] == 1
-    ack_seq = si[:, 1]
-    started = si[:, 2] == 1
-    rto = scal_f_ref[...][:, 0]
-    state, n_to, spur, un = R.ring_drain_ref(
-        t, rto, started, has_ack, ack_seq, lbits_ref[...], bitmap_ref[...],
+    state, n_to, spur, un = R.drain_tile(
+        t_ref[0], scal_f_ref[:, 0:1], si[:, 2:3] == 1, si[:, 0:1] == 1,
+        si[:, 1:2], lbits_ref[...], bitmap_ref[...],
         s0_ref[...], s1_ref[...], s2_ref[...], w=w, ww=ww, maxw=maxw)
     state_ref[...] = state
-    rows = n_to.shape[0]
-    counts_ref[...] = jnp.concatenate(
-        [n_to[:, None], spur[:, None], un[:, None],
-         jnp.zeros((rows, LANES - 3), I32)], axis=1)
+    # counts in lanes 0/1/2 of a lane-dense tile (rest zero)
+    lane = jax.lax.broadcasted_iota(I32, counts_ref.shape, 1)
+    counts_ref[...] = jnp.where(
+        lane == 0, n_to, jnp.where(lane == 1, spur,
+                                   jnp.where(lane == 2, un, 0)))
 
 
 @functools.partial(jax.jit, static_argnames=("w", "ww", "maxw", "interpret"))
 def ring_drain(t, rto, started, has_ack, ack_seq, lbits, bitmap,
                sent0, sent1, sent2, *, w: int, ww: int, maxw: int,
-               interpret: bool = True):
+               interpret: bool):
     """Blocked sent-ring drain over the flow table.
 
     Same contract as ``ref.ring_drain_ref`` with unpadded [F]/[F, w]/
@@ -79,14 +78,14 @@ def ring_drain(t, rto, started, has_ack, ack_seq, lbits, bitmap,
     state, counts = pl.pallas_call(
         functools.partial(_kernel, w=w, ww=ww, maxw=maxw),
         grid=(fp // BLOCK_ROWS,),
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   tile(LANES), tile(LANES), tile(wwp), tile(mwp),
                   tile(wp), tile(wp), tile(wp)],
         out_specs=[tile(wp), tile(LANES)],
         out_shape=[jax.ShapeDtypeStruct((fp, wp), I32),
                    jax.ShapeDtypeStruct((fp, LANES), I32)],
         interpret=interpret,
-    )(jnp.asarray(t, I32).reshape(1, 1), scal_i, scal_f,
+    )(jnp.asarray(t, I32).reshape(1), scal_i, scal_f,
       _pad2(lbits, fp, wwp), _pad2(bitmap, fp, mwp),
       _pad2(sent0, fp, wp), _pad2(sent1, fp, wp), _pad2(sent2, fp, wp))
     return (state[:f, :w], counts[:f, 0], counts[:f, 1], counts[:f, 2])

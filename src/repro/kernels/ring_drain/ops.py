@@ -8,16 +8,15 @@ callable ``transport.control`` folds its ACK/trim/timeout events through:
 
 with the contract of ``ref.ring_drain_ref`` (unpadded inputs).  Both
 backends are bit-for-bit interchangeable (asserted engine-deep in
-tests/test_engine_pallas.py); ``pallas`` runs in interpret mode off-TPU,
-exactly like the ``cc_update`` registry entry.
+tests/test_engine_pallas.py); ``pallas`` compiles through Mosaic on a
+TPU and runs in interpret mode elsewhere (``kernels.interpret_mode``).
 """
 
 from __future__ import annotations
 
 import functools
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.ring_drain import kernel as K
 from repro.kernels.ring_drain import ref as R
 
@@ -25,8 +24,7 @@ BACKENDS = ("jnp", "pallas")
 
 
 def ring_drain(t, rto, started, has_ack, ack_seq, lbits, bitmap,
-               sent0, sent1, sent2, *, backend: str = "jnp",
-               interpret: bool = True):
+               sent0, sent1, sent2, *, backend: str, interpret: bool):
     w = sent0.shape[1]
     ww = lbits.shape[1]
     maxw = bitmap.shape[1]
@@ -45,4 +43,4 @@ def get(backend: str):
         raise KeyError(
             f"unknown transport backend {backend!r}; have {BACKENDS}")
     return functools.partial(ring_drain, backend=backend,
-                             interpret=jax.default_backend() != "tpu")
+                             interpret=interpret_mode())

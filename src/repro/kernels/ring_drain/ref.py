@@ -5,9 +5,9 @@ state plane:
 
   1. free the slot matched by this tick's cumulative ACK,
   2. mark trim-notified slots lost (the [NF, WW] loss-bitmap words from the
-     trim ring, expanded arithmetically — ``(word >> bit) & 1`` over an
-     iota — instead of the [NF, W] advanced gather the phase used to pay
-     XLA:CPU scatter prices for),
+     trim ring, expanded arithmetically — ``(word >> bit) & 1`` over a
+     lane iota — instead of the [NF, W] advanced gather the phase used to
+     pay XLA:CPU scatter prices for),
   3. fire retransmission timeouts (with the spurious-retx audit against
      the receiver dedupe bitmap, a static ``MAXW``-step select instead of
      a per-element gather),
@@ -15,8 +15,8 @@ state plane:
 and reduces the per-flow timeout / spurious / still-outstanding counts the
 transport needs.  Everything is elementwise + row reductions over the
 [NF, W] tile — no gathers, no scatters — which is both the fast jnp path
-on CPU and, verbatim, the Pallas kernel body (``kernel.py`` calls this
-function on VMEM-resident tiles, so kernel and oracle cannot drift).
+on CPU and, verbatim, the Pallas kernel body (``kernel.py`` calls
+``drain_tile`` on VMEM-resident tiles, so kernel and oracle cannot drift).
 
 Inputs may be lane-padded beyond the true ring width ``w`` (the Pallas
 tiles are); padded lanes hold zeros and provably stay inert: a zero state
@@ -50,8 +50,20 @@ def ring_drain_ref(t, rto, started, has_ack, ack_seq, lbits, bitmap,
     (same padded width as ``sent0``) and per-flow i32 counts of fired
     timeouts, spurious retransmissions, and still-outstanding packets.
     """
+    state, n_to, spur, un = drain_tile(
+        t, rto[:, None], started[:, None], has_ack[:, None],
+        ack_seq[:, None], lbits, bitmap, sent0, sent1, sent2,
+        w=w, ww=ww, maxw=maxw)
+    return state, n_to[:, 0], spur[:, 0], un[:, 0]
+
+
+def drain_tile(t, rto, started, has_ack, ack_seq, lbits, bitmap,
+               sent0, sent1, sent2, *, w: int, ww: int, maxw: int):
+    """:func:`ring_drain_ref` on 2-D operands only: the per-flow inputs
+    are ``[F, 1]`` columns and the counts come back as ``[F, 1]``, so the
+    Pallas kernel can run it on its tiles as they are."""
     f, wt = sent0.shape                               # wt >= w (padding)
-    wbits = jnp.arange(wt, dtype=I32)
+    lane = jnp.arange(wt, dtype=I32)[None, :]
 
     # 1. ACK frees its slot when the slot still holds that sequence.
     #    ``hit`` is one-hot per row (aslot < w <= wt), so "the hit lane
@@ -60,27 +72,28 @@ def ring_drain_ref(t, rto, started, has_ack, ack_seq, lbits, bitmap,
     #    XLA fusion that re-streams the [F, W] planes, so fewer
     #    reductions is fewer passes (DESIGN.md Sec. 6.4)
     aslot = ack_seq % w
-    hit = wbits[None, :] == aslot[:, None]
+    hit = lane == aslot
     match = has_ack & jnp.any(
-        hit & (sent0 != 0) & (sent1 == ack_seq[:, None]), axis=1)
-    state = jnp.where(match[:, None] & hit, 0, sent0)
+        hit & (sent0 != 0) & (sent1 == ack_seq), axis=1, keepdims=True)
+    state = jnp.where(match & hit, 0, sent0)
 
-    # 2. trim-notified packets -> lost (awaiting retransmission)
-    bits = ((lbits[:, :ww, None] >> jnp.arange(32, dtype=I32)) & 1)
-    bits = bits.reshape(f, ww * 32)                   # == [F, w]
-    if wt > w:
-        bits = jnp.pad(bits, ((0, 0), (0, wt - w)))
-    lost = (bits == 1) & (state == 1)
+    # 2. trim-notified packets -> lost (awaiting retransmission).  Lane j
+    #    reads bit j % 32 of loss word j // 32 (w == 32 * ww; padded lanes
+    #    match no word and read 0) — a static ww-step select, so the
+    #    expansion needs no reshape of the lane axis
+    word = jnp.zeros_like(sent0)
+    for wd in range(ww):                              # static, small
+        word = word + jnp.where(lane // 32 == wd, lbits[:, wd:wd + 1], 0)
+    lost = (((word >> (lane % 32)) & 1) == 1) & (state == 1)
     state = jnp.where(lost, 3, state)
 
     # 3. timeouts, with the spurious-retx audit against the receiver
     #    dedupe bitmap (does the receiver already hold this sequence?)
-    to_mask = (state == 1) & \
-        ((t - sent2).astype(F32) > rto[:, None]) & started[:, None]
+    to_mask = (state == 1) & ((t - sent2).astype(F32) > rto) & started
     sp_word = sent1 // 32
     bm = jnp.zeros_like(sent1)
     for wd in range(maxw):                            # static, small
-        bm = bm + jnp.where(sp_word == wd, bitmap[:, wd, None], 0)
+        bm = bm + jnp.where(sp_word == wd, bitmap[:, wd:wd + 1], 0)
     already = ((bm >> (sent1 % 32)) & 1) == 1
     state = jnp.where(to_mask, 3, state)
 
@@ -92,12 +105,14 @@ def ring_drain_ref(t, rto, started, has_ack, ack_seq, lbits, bitmap,
         packed = jnp.sum(
             (to_mask.astype(I32) << 20)
             + ((to_mask & already).astype(I32) << 10)
-            + (state == 1).astype(I32), axis=1)
+            + (state == 1).astype(I32), axis=1, keepdims=True)
         n_to = packed >> 20
         spur = (packed >> 10) & 1023
         unacked_pkts = packed & 1023
     else:                                             # unbounded fallback
-        n_to = jnp.sum(to_mask.astype(I32), axis=1)
-        spur = jnp.sum((to_mask & already).astype(I32), axis=1)
-        unacked_pkts = jnp.sum((state == 1).astype(I32), axis=1)
+        n_to = jnp.sum(to_mask.astype(I32), axis=1, keepdims=True)
+        spur = jnp.sum((to_mask & already).astype(I32), axis=1,
+                       keepdims=True)
+        unacked_pkts = jnp.sum((state == 1).astype(I32), axis=1,
+                               keepdims=True)
     return state, n_to, spur, unacked_pkts

@@ -48,7 +48,7 @@ def _ssd_kernel(x_ref, loga_ref, b_ref, c_ref, y_ref, s_ref, t_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_chunk_scan(x, loga, B, C, *, chunk: int = 128, interpret: bool = True):
+def ssd_chunk_scan(x, loga, B, C, *, chunk: int = 128, interpret: bool):
     """Intra-chunk SSD pass.
 
     Args:

@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.ssd_scan.kernel import ssd_chunk_scan
 
 
@@ -40,11 +41,11 @@ def _inter_chunk(y_intra, s_chunk, t_chunk, loga, C_mat, chunk):
     return y_intra + y_inter.reshape(BH, L, P), final_state
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(x, loga, B, C, *, chunk: int = 128, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd(x, loga, B, C, *, chunk: int = 128):
     """Pallas-backed SSD: x,[BH,L,P] loga,[BH,L] B/C,[BH,L,N] -> y [BH,L,P]."""
     y_intra, s_chunk, t_chunk = ssd_chunk_scan(x, loga, B, C, chunk=chunk,
-                                               interpret=interpret)
+                                               interpret=interpret_mode())
     y, _ = _inter_chunk(y_intra, s_chunk, t_chunk, loga, C, chunk)
     return y
 

@@ -19,9 +19,17 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh for CPU smoke paths."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    # the models place activations with ``with_sharding_constraint`` and
+    # let the partitioner choose the rest, which needs Auto axes
+    # (``jax.make_mesh`` defaults to Explicit ones)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

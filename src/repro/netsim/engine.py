@@ -100,17 +100,16 @@ class Sim:
     horizon: callable       # SimState -> i32 (consts bound)
     init: callable          # () -> SimState
 
-    def _leap_horizon(self):
-        return self.horizon if self.dims.leap else None
-
     def run(self, max_ticks: int, seed: int = 0) -> SimState:
         """Run to completion.  ``seed`` sets the per-run hash salt
         (RED/ECMP decorrelation) — seed 0 is the historical default."""
         st0 = self.init()
         if seed:
             st0 = st0._replace(salt=jnp.asarray(seed, I32))
-        return _run_until_done(self.step, self._leap_horizon(), st0,
-                               max_ticks, self.dims.superstep)
+        return _run_until_done(self.step_fn,
+                               self.horizon_fn if self.dims.leap else None,
+                               self.consts, st0, max_ticks,
+                               self.dims.superstep)
 
     def run_trace(self, ticks: int, trace_flows: int = 8):
         return _run_trace(self.step, self.init(), ticks, trace_flows)
@@ -264,13 +263,19 @@ def _leap(horizon, max_ticks):
     return leap
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 3, 4), donate_argnums=(2,))
-def _run_until_done(step, horizon, state0: SimState, max_ticks: int,
-                    superstep: int) -> SimState:
+@functools.partial(jax.jit, static_argnums=(0, 1, 4, 5), donate_argnums=(3,))
+def _run_until_done(step_fn, horizon_fn, consts: Consts, state0: SimState,
+                    max_ticks: int, superstep: int) -> SimState:
+    # ``consts`` is an argument, as in the lane loop, and not closed over:
+    # closed-over scalars become literals that XLA folds into the f32
+    # arithmetic (``cwnd / bdp * fd`` -> ``cwnd * c``), which rounds
+    # differently from the lane loop on the TPU
     def cond(st):
         return (st.now < max_ticks) & ~jnp.all(st.done)
 
-    leap = _leap(horizon, max_ticks) if horizon is not None else None
+    step = functools.partial(step_fn, consts)
+    leap = (_leap(functools.partial(horizon_fn, consts), max_ticks)
+            if horizon_fn is not None else None)
     return _superstep_loop(step, cond, superstep, leap)(state0)
 
 

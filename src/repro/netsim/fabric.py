@@ -157,14 +157,12 @@ def departures(dims: Dims, consts: Consts, st: SimState) -> SimState:
 
 
 def arrivals(dims: Dims, consts: Consts, st: SimState,
-             enqueue=None) -> SimState:
+             enqueue) -> SimState:
     """Phase 2: land this tick's wire slot — deliver at the edge (dedupe,
     ACK generation) or enqueue mid-fabric (trim/drop on overflow).
 
     ``enqueue`` is the backend-resolved enqueue-rank callable
-    (``kernels/enqueue_arb/ops.get``); ``None`` means the pure-jnp
-    reference (the engine passes the ``SimConfig.fabric_backend``
-    resolution)."""
+    (``kernels/enqueue_arb/ops.get`` of ``SimConfig.fabric_backend``)."""
     t = st.now
     m = st.m
     NF, NQ, NE, N = dims.NF, dims.NQ, dims.NE, dims.N
@@ -257,9 +255,6 @@ def arrivals(dims: Dims, consts: Consts, st: SimState,
     # compact enumeration is id-ascending, so group slot order is
     # unchanged; kernels/enqueue_arb — the jnp reference and the Pallas
     # kernel are interchangeable backends).
-    if enqueue is None:
-        from repro.kernels.enqueue_arb import ops as _arb_ops
-        enqueue = _arb_ops.enqueue_rank
     earr = arr[consts.enq_ids]                         # [EQ, 7]
     e_dstq, e_flow, e_seq, e_ent, e_ecn, e_ts = (
         earr[:, i] for i in range(1, 7))

@@ -127,6 +127,19 @@ def summarize(sim, st) -> dict:
     return out
 
 
+def conservation_ledger(dims, st) -> tuple:
+    """Packet conservation over a host copy of a state: ``(sent,
+    accounted)``, equal at every tick boundary.  Every emitted data packet
+    (first sends ``sum(next_seq)`` plus retransmissions ``n_retx``) is
+    delivered, trimmed, dropped, blackholed, queued or on the wire."""
+    sent = int(np.sum(np.asarray(st.next_seq))) + int(st.m.n_retx)
+    on_wire = int(np.sum(np.asarray(st.infl)[:, :, 0] == 1))
+    queued = int(np.sum(np.asarray(st.q_size)[:dims.NQ]))
+    sunk = (int(st.m.delivered_pkts) + int(st.m.n_trim)
+            + int(st.m.n_drop) + int(st.m.n_black))
+    return sent, sunk + on_wire + queued
+
+
 def jain_fairness(values: np.ndarray) -> float:
     v = np.asarray(values, np.float64)
     if v.sum() == 0:

@@ -58,17 +58,13 @@ def _grant_demand(dims: Dims, consts: Consts, st: SimState):
         < consts.credit_window)
 
 
-def grants(dims: Dims, consts: Consts, st: SimState, arb=None) -> SimState:
+def grants(dims: Dims, consts: Consts, st: SimState, arb) -> SimState:
     """Phase 4: EQDS receiver credit grants (paper Sec. 2.2).
 
     ``arb`` is the backend-resolved round-robin arbitration callable
-    (``kernels/enqueue_arb/ops.get``); ``None`` means the pure-jnp
-    reference."""
+    (``kernels/enqueue_arb/ops.get``)."""
     if not dims.credit_based:
         return st
-    if arb is None:
-        from repro.kernels.enqueue_arb import ops as _arb_ops
-        arb = _arb_ops.rr_pick
     t = st.now
     NF, N, R, FRMAX = dims.NF, dims.N, dims.R, dims.FRMAX
     MTU = float(dims.mtu)
@@ -129,15 +125,11 @@ def admission(dims: Dims, consts: Consts, st: SimState):
     return elig, has_retx, seq_emit, nsize
 
 
-def sends(dims: Dims, consts: Consts, st: SimState, arb=None) -> SimState:
+def sends(dims: Dims, consts: Consts, st: SimState, arb) -> SimState:
     """Phase 5: one packet per NIC per tick, arbitration + admission.
 
     ``arb`` is the backend-resolved round-robin arbitration callable
-    (``kernels/enqueue_arb/ops.get``); ``None`` means the pure-jnp
-    reference."""
-    if arb is None:
-        from repro.kernels.enqueue_arb import ops as _arb_ops
-        arb = _arb_ops.rr_pick
+    (``kernels/enqueue_arb/ops.get``)."""
     t = st.now
     m = st.m
     NF, N, NQ, L, W = dims.NF, dims.N, dims.NQ, dims.L, dims.W

@@ -4,8 +4,7 @@ The experiment API lowers a ``Scenario x points x seeds`` grid onto one
 ``[B = P*S]`` lane batch (``netsim/api.py``).  This module is the
 executor under it: the *lane loop* — the per-lane gated, per-lane
 leaping superstep loop — plus the machinery that partitions a lane
-batch across every host/accelerator device through
-``jax.experimental.shard_map``:
+batch across every host/accelerator device through ``jax.shard_map``:
 
 * ``lane_loop``        the vmapped loop as a pure ``(consts_b, states)
                        -> states`` function (shared verbatim by the
@@ -48,7 +47,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -194,9 +192,9 @@ def _run_lanes_sharded(step_fn, horizon_fn, axes, max_ticks: int,
     loop = lane_loop(step_fn, horizon_fn, axes, max_ticks, superstep)
     _, treedef = jax.tree_util.tree_flatten(consts_b)
     state_specs, consts_specs = _specs(states, axes, treedef)
-    sharded = shard_map(loop, mesh=mesh,
-                        in_specs=(consts_specs, state_specs),
-                        out_specs=state_specs, check_rep=False)
+    sharded = jax.shard_map(loop, mesh=mesh,
+                            in_specs=(consts_specs, state_specs),
+                            out_specs=state_specs, check_vma=False)
     return sharded(consts_b, states)
 
 

@@ -38,17 +38,12 @@ def effective_rto(dims: Dims, consts: Consts, st: SimState):
 
 
 def control(dims: Dims, consts: Consts, cc_update, st: SimState,
-            drain=None) -> SimState:
+            drain) -> SimState:
     """Phase 3: ACK / trim / timeout / credit events -> transport state,
     CC update (``cc_update`` resolved by the registry), LB update.
 
     ``drain`` is the backend-resolved sent-ring drain callable
-    (``kernels/ring_drain/ops.get``); ``None`` means the pure-jnp
-    reference (the engine passes the ``SimConfig.transport_backend``
-    resolution)."""
-    if drain is None:
-        from repro.kernels.ring_drain import ops as _drain_ops
-        drain = _drain_ops.ring_drain
+    (``kernels/ring_drain/ops.get`` of ``SimConfig.transport_backend``)."""
     t = st.now
     m = st.m
     NF, N, R, W = dims.NF, dims.N, dims.R, dims.W

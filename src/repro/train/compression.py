@@ -79,15 +79,14 @@ def make_compressed_allreduce(mesh, axis_name: str = "data"):
     gradient vector — exactly what per-replica backward passes produce.
     The result rows all equal the int8-compressed mean.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     world = dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name]
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(axis_name, None), P(axis_name, None)),
                        out_specs=(P(axis_name, None), P(axis_name, None)),
-                       check_rep=False)
+                       check_vma=False)
     def _run(g_local, err_local):
         out, err = compressed_psum_mean(g_local[0], err_local[0],
                                         axis_name, world)

@@ -25,8 +25,7 @@ def _rules_of(findings):
 
 
 def test_jx001_f64_leak_trips():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0)(np.zeros(4, np.float32))
     found = audit.check_jaxpr(closed, "toy/f64")

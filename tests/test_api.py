@@ -95,6 +95,26 @@ def test_study_lanes_match_standalone_three_tier():
                                 _lane(res.states, pi * len(seeds) + si))
 
 
+def test_sim_run_passes_consts_as_arguments(monkeypatch):
+    """``Sim.run`` hands ``Consts`` to its compiled loop as arguments, as
+    the lane loop does.  Closed over, the CC parameters become literals
+    that XLA folds into the f32 window arithmetic (``cwnd / bdp * fd``
+    into one multiply), and on a TPU the standalone run then rounds
+    ``cc.cwnd`` differently from the same run as a Study lane."""
+    sim = scenario("tiny_3t").build()
+    lowered = []
+    real = engine._run_until_done
+
+    def spy(*args):
+        lowered.append(real.lower(*args).as_text())
+        return real(*args)
+
+    monkeypatch.setattr(engine, "_run_until_done", spy)
+    sim.run(max_ticks=50)
+    bdp = f"dense<{float(sim.consts.cc.bdp):.6e}>"
+    assert len(lowered) == 1 and bdp not in lowered[0]
+
+
 def test_build_sweep_lanes_match_study():
     """Compatibility wrapper: ``build_sweep`` runs the same lane loop, so
     its [P] states are bit-identical to the seed-0 lanes of a Study over

@@ -29,6 +29,7 @@ import pytest
 from repro.netsim import workloads
 from repro.netsim.engine import SimConfig, build
 from repro.netsim.faults import FaultEvent, FaultSchedule, Flap
+from repro.netsim.metrics import conservation_ledger
 from repro.netsim.units import FatTreeConfig, LinkConfig
 
 try:
@@ -87,15 +88,6 @@ def _recovery_knobs(seed: int) -> dict:
     return {}
 
 
-def _conservation_ledger(dims, st):
-    sent = int(np.sum(np.asarray(st.next_seq))) + int(st.m.n_retx)
-    on_wire = int(np.sum(np.asarray(st.infl)[:, :, 0] == 1))
-    queued = int(np.sum(np.asarray(st.q_size)[:dims.NQ]))
-    sunk = (int(st.m.delivered_pkts) + int(st.m.n_trim)
-            + int(st.m.n_drop) + int(st.m.n_black))
-    return sent, sunk + on_wire + queued
-
-
 def check_conservation(seed: int, ticks: int = 400) -> None:
     wl = workloads.permutation(TREE3, size_bytes=24 * 4096, seed=seed)
     sched = chaos_schedule(seed)
@@ -105,7 +97,7 @@ def check_conservation(seed: int, ticks: int = 400) -> None:
     s = sim.init()
     for t in range(ticks):
         s = step(s)
-        sent, accounted = _conservation_ledger(sim.dims, s)
+        sent, accounted = conservation_ledger(sim.dims, s)
         assert sent == accounted, (
             f"seed {seed} tick {t + 1}: {sent} sent, {accounted} accounted"
             f"\nschedule: {sched}")

@@ -22,6 +22,7 @@ import pytest
 
 from repro.netsim import workloads
 from repro.netsim.engine import SimConfig, build
+from repro.netsim.metrics import conservation_ledger
 from repro.netsim.units import FatTreeConfig, LinkConfig
 
 LINK = LinkConfig()
@@ -30,22 +31,13 @@ TREE3 = FatTreeConfig(racks=4, nodes_per_rack=2, uplinks=2,
                       pods=2, core_uplinks=1)                      # core 2:1
 
 
-def _conservation_ledger(dims, st):
-    sent = int(np.sum(np.asarray(st.next_seq))) + int(st.m.n_retx)
-    on_wire = int(np.sum(np.asarray(st.infl)[:, :, 0] == 1))
-    queued = int(np.sum(np.asarray(st.q_size)[:dims.NQ]))
-    sunk = (int(st.m.delivered_pkts) + int(st.m.n_trim)
-            + int(st.m.n_drop) + int(st.m.n_black))
-    return sent, sunk + on_wire + queued
-
-
 def _check_conservation(tree, wl, ticks, **cfg_kw):
     sim = build(SimConfig(link=LINK, tree=tree, **cfg_kw), wl)
     step = jax.jit(sim.step)
     st = sim.init()
     for t in range(ticks):
         st = step(st)
-        sent, accounted = _conservation_ledger(sim.dims, st)
+        sent, accounted = conservation_ledger(sim.dims, st)
         assert sent == accounted, (
             f"tick {t + 1}: {sent} packets sent but {accounted} accounted "
             f"(delivered+trimmed+dropped+blackholed+queued+on-wire)")

@@ -7,6 +7,7 @@ import pytest
 
 from repro.core.smartt import smartt_update
 from repro.core.types import CCEvent, init_cc_state, make_cc_params
+from repro.kernels import interpret_mode
 from repro.kernels.cc_update.ops import smartt_update_pallas
 from repro.kernels.flash_attn.ops import gqa_flash_attention
 from repro.kernels.flash_attn.ref import attention_ref
@@ -54,7 +55,7 @@ def _random_cc(F, seed):
 def test_cc_update_kernel_matches_oracle(F):
     p, s, ev = _random_cc(F, F)
     ref = smartt_update(p, s, ev, 42.0)
-    out = smartt_update_pallas(p, s, ev, 42.0)
+    out = smartt_update_pallas(p, s, ev, 42.0, interpret=interpret_mode())
     for name in ("cwnd", "acked", "qa_end", "trigger_qa", "bytes_to_ignore",
                  "bytes_ignored", "fi_count", "fi_active", "avg_wtd",
                  "ack_count"):
@@ -73,7 +74,8 @@ def test_red_mark_matches_oracle(Q, tick):
     rng = np.random.default_rng(Q + tick)
     qs = jnp.asarray(rng.integers(0, 27, Q), jnp.int32)
     ar = jnp.asarray(rng.integers(0, 6, Q), jnp.int32)
-    got = red_mark(qs, ar, 26, 5.2, 20.8, tick, 0xECD)
+    got = red_mark(qs, ar, 26, 5.2, 20.8, tick, 0xECD,
+                   interpret=interpret_mode())
     want = red_mark_ref(qs, ar, jnp.int32(26), jnp.float32(5.2),
                         jnp.float32(20.8), jnp.int32(tick), jnp.int32(0xECD))
     for g, w in zip(got, want):
@@ -86,7 +88,8 @@ def test_red_mark_probability_is_red_shaped():
     for q, lo, hi in ((4, 0.0, 0.01), (13, 0.4, 0.6), (25, 0.99, 1.01)):
         qs = jnp.full((Q,), q, jnp.int32)
         mark, _, _ = red_mark(qs, jnp.zeros((Q,), jnp.int32),
-                              26, 5.2, 20.8, 3, 0xECD)
+                              26, 5.2, 20.8, 3, 0xECD,
+                              interpret=interpret_mode())
         frac = float(jnp.mean(mark.astype(jnp.float32)))
         assert lo <= frac <= hi, (q, frac)
 

@@ -66,3 +66,25 @@ def test_train_learns_and_restarts(tmp_path):
                                      log_every=100),
                           dcfg, log=lambda *_: None)
     assert len(losses2) == 5           # resumed at 25, ran 5 more
+
+
+def test_bench_run_exits_nonzero_when_a_figure_fails(monkeypatch, capsys):
+    """benchmarks.run prints every figure's error row, keeps going, and
+    exits non-zero if any figure failed."""
+    from benchmarks import fig_benchmarks, run
+    from repro import compile_cache
+
+    def fig_fine():
+        return ["fig_fine,1.0,ok"]
+
+    def fig_broken():
+        raise ValueError("boom")
+
+    monkeypatch.setattr(compile_cache, "use_compile_cache", lambda: None)
+    monkeypatch.setattr(fig_benchmarks, "ALL_FIGS", [fig_broken, fig_fine])
+    assert run.main([]) == 1
+    out = capsys.readouterr().out
+    assert "fig_broken,0,ERROR:ValueError:boom" in out
+    assert "# total wall" in out and "; 1 rows" in out   # fig_fine ran
+    monkeypatch.setattr(fig_benchmarks, "ALL_FIGS", [fig_fine])
+    assert run.main([]) == 0
