@@ -165,7 +165,7 @@ def check_kernel_parity(kernels_dir: Path | None = None) -> list:
 # sections whose row names are `scenario/...` when no explicit
 # ``scenario`` field is present; other sections are skipped
 _NAME_PREFIX_SECTIONS = ("perf", "studies", "studies_quick", "failover",
-                         "phase_profile", "study_throughput", "collectives")
+                         "study_throughput", "collectives")
 
 
 def check_ledger_keys(bench_json: Path | None = None) -> list:

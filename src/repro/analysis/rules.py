@@ -118,10 +118,6 @@ ALLOWLIST: dict[str, str] = {
     "JX101:kernels/cc_update:*":
         "kernel hoists `now` next to param_vec for scalar prefetch; "
         "ops.py owns the adaptation",
-    # perm_32n_flat is built inline by benchmarks/profile_tick.py (the
-    # N=32 profiling point below the smallest registered 3-tier tree).
-    "JX102:*:perm_32n_flat":
-        "ad-hoc profiling scenario built in benchmarks/profile_tick.py",
 }
 
 

@@ -240,16 +240,20 @@ class RunResult:
     repair_ticks: tuple = ()  # schedule transitions back to all-healthy
     first_fault: int = -1     # first fault-active tick (-1 = never)
     wall_s: float | None = None
+    counters: engine.LoopCounters | None = None  # ints, from run(counters=)
     state: state.SimState | None = dataclasses.field(default=None)
 
     @classmethod
     def from_state(cls, sim: engine.Sim, st: state.SimState, *,
                    scenario: str, point=(), seed: int = 0,
                    max_ticks: int, wall_s: float | None = None,
-                   flow_meta: dict | None = None) -> "RunResult":
+                   flow_meta: dict | None = None,
+                   counters: engine.LoopCounters | None = None,
+                   ) -> "RunResult":
         """Build from a (host or device) final state.  ``flow_meta`` lets a
         Study hoist the per-flow constants (size/t_start/flow_brtt host
-        copies) out of its per-lane loop."""
+        copies) out of its per-lane loop; ``counters`` are the run loop's
+        (``Sim.run(..., counters=True)``)."""
         if flow_meta is None:
             flow_meta = _flow_meta(sim)
         m = st.m
@@ -290,7 +294,9 @@ class RunResult:
             delivered_bytes_fault=float(m.delivered_bytes_fault),
             goodput_hist=np.asarray(m.goodput_hist),
             goodput_bin=eff_gb, **fault_meta,
-            wall_s=wall_s, state=st)
+            wall_s=wall_s, state=st,
+            counters=(None if counters is None else engine.LoopCounters(
+                *(int(x) for x in counters))))
 
     # -- flow-level views ---------------------------------------------------
 
@@ -516,7 +522,11 @@ class RunResult:
         return d
 
     def summary(self) -> dict:
-        """Legacy ``metrics.summarize``-shaped dict (compat helper)."""
+        """Legacy ``metrics.summarize``-shaped dict (compat helper), with
+        the run loop's counters under ``loop`` when the run counted them:
+        how many ticks stepped, and how many the leap skipped."""
+        loop = {} if self.counters is None else dict(
+            loop=self.counters._asdict())
         return dict(
             ticks=self.ticks, all_done=self.all_done, n_done=self.n_done,
             fct_ticks=self.fct, fct_max=self.completion,
@@ -530,7 +540,7 @@ class RunResult:
             spurious_retx=self.spurious_retx,
             spurious_frac=self.spurious_frac, rtt_hist=self.rtt_hist,
             q_mean=self.q_mean, q_max=self.q_max,
-            goodput_bytes=self.goodput, mtu=self.mtu)
+            goodput_bytes=self.goodput, mtu=self.mtu, **loop)
 
     def __repr__(self) -> str:
         return (f"RunResult({self.name}: ticks={self.ticks} "
@@ -669,11 +679,21 @@ class Study:
         The freshly built lane batch is donated to the run loop.  With
         ``mesh`` the batch shards across its devices (``shard.run_lanes``
         — bit-identical to the single-device path)."""
-        mt = self._max_ticks(max_ticks)
+        with jax.profiler.TraceAnnotation("netsim.study.init"):
+            states = self.init()
+        return self._lanes(self.consts_b, states,
+                           self._max_ticks(max_ticks), mesh)
+
+    def _lanes(self, consts_b, states, max_ticks: int,
+               mesh) -> state.SimState:
+        """The lane loop over ``states``, run to its end."""
         horizon_fn = self.sim.horizon_fn if self.sim.dims.leap else None
-        return shard.run_lanes(self.sim.step_fn, horizon_fn, self.axes, mt,
-                               self.sim.dims.superstep, self.consts_b,
-                               self.init(), mesh=mesh)
+        with jax.profiler.TraceAnnotation("netsim.study.lanes"):
+            out = shard.run_lanes(self.sim.step_fn, horizon_fn, self.axes,
+                                  max_ticks, self.sim.dims.superstep,
+                                  consts_b, states, mesh=mesh)
+            out.now.block_until_ready()
+        return out
 
     def _run_lane_subset(self, lanes, max_ticks: int,
                          mesh=None) -> state.SimState:
@@ -682,12 +702,11 @@ class Study:
         batch-composition-independent (per-lane gating/leaping), so the
         result is bit-equal to the same lanes of a full-grid run."""
         lanes = np.asarray(lanes, np.int64)
-        consts_sub = self._consts_subset(lanes)
-        states = self._init_lanes(consts_sub, np.asarray(self.salts)[lanes])
-        horizon_fn = self.sim.horizon_fn if self.sim.dims.leap else None
-        return shard.run_lanes(self.sim.step_fn, horizon_fn, self.axes,
-                               max_ticks, self.sim.dims.superstep,
-                               consts_sub, states, mesh=mesh)
+        with jax.profiler.TraceAnnotation("netsim.study.init"):
+            consts_sub = self._consts_subset(lanes)
+            states = self._init_lanes(consts_sub,
+                                      np.asarray(self.salts)[lanes])
+        return self._lanes(consts_sub, states, max_ticks, mesh)
 
     def lane_keys(self, max_ticks: int | None = None) -> list:
         """Content address of every lane (``cache.lane_key``) — the
@@ -731,11 +750,11 @@ class Study:
         t0 = time.time()
         if rc is None and chunk_lanes is None:
             states = self.run_states(mt, mesh=mesh)
-            states.now.block_until_ready()
             # one bulk device->host transfer; lanes then slice numpy (the
             # per-lane RunResults would otherwise issue ~25 tiny
             # transfers per lane)
-            states_h = jax.device_get(states)
+            with jax.profiler.TraceAnnotation("netsim.study.gather"):
+                states_h = jax.device_get(states)
             hits, misses = 0, self.n_lanes
         else:
             states_h, hits, misses = self._run_stitched(
@@ -773,7 +792,9 @@ class Study:
         cd = cache_mod.code_digest() if rc is not None else None
         for lo in range(0, len(missing), step):
             chunk = missing[lo:lo + step]
-            out_h = jax.device_get(self._run_lane_subset(chunk, mt, mesh))
+            out = self._run_lane_subset(chunk, mt, mesh)
+            with jax.profiler.TraceAnnotation("netsim.study.gather"):
+                out_h = jax.device_get(out)
             for j, lane in enumerate(chunk):
                 lane_st = jax.tree.map(lambda x: x[j], out_h)
                 lane_states[lane] = lane_st
@@ -828,19 +849,24 @@ def study(sc, points=None, seeds=(0,), **scenario_overrides) -> Study:
 
 
 def run(sc, *, seed: int = 0, max_ticks: int | None = None,
-        **scenario_overrides) -> RunResult:
+        counters: bool = False, **scenario_overrides) -> RunResult:
     """Run one scenario standalone (unbatched ``Sim.run``) -> RunResult.
 
     ``sc`` is a :class:`Scenario` or a registered name; ``overrides`` are
-    forwarded to :meth:`Scenario.with_` (``algo=``, ``lb=``, ...)."""
+    forwarded to :meth:`Scenario.with_` (``algo=``, ``lb=``, ...).  With
+    ``counters`` the result's ``counters`` (and ``summary()["loop"]``)
+    say how many ticks the run loop stepped and how many it leapt — why
+    a run took as long as it did."""
     sc = _resolve(sc)
     if scenario_overrides:
         sc = sc.with_(**scenario_overrides)
     mt = int(max_ticks if max_ticks is not None else sc.max_ticks)
     sim = engine.build(sc.cfg, sc.wl)   # derive validates the workload
     t0 = time.time()
-    st = sim.run(max_ticks=mt, seed=seed)
+    st, ctr = (sim.run(max_ticks=mt, seed=seed, counters=True) if counters
+               else (sim.run(max_ticks=mt, seed=seed), None))
     st.now.block_until_ready()
     wall = time.time() - t0
     return RunResult.from_state(sim, jax.device_get(st), scenario=sc.name,
-                                seed=seed, max_ticks=mt, wall_s=wall)
+                                seed=seed, max_ticks=mt, wall_s=wall,
+                                counters=jax.device_get(ctr))

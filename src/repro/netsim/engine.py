@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +77,20 @@ F32 = jnp.float32
 _STEP_TRACES = _trace_counter("engine.step")
 
 
+class LoopCounters(NamedTuple):
+    """What a run loop did, counted on the device beside the state (never
+    a ``SimState`` leaf): ``ticks_executed`` gated ticks that stepped,
+    ``supersteps`` while iterations, ``leaps`` leaps with a distance > 0,
+    ``ticks_leapt`` the sum of those distances.  Scalars for a single run;
+    in the lane loop one entry per lane (``supersteps`` counts the
+    iterations of the loop that ran the lane)."""
+
+    ticks_executed: jax.Array
+    supersteps: jax.Array
+    leaps: jax.Array
+    ticks_leapt: jax.Array
+
+
 @dataclasses.dataclass(frozen=True)
 class Sim:
     """Compiled simulator bundle."""
@@ -89,27 +104,30 @@ class Sim:
     dims: Dims
     consts: Consts
     phases: tuple           # ordered ((name, (Consts, SimState) -> SimState),
-                            #   ...) — the six tick sub-steps step_fn composes;
-                            # the phase profiler (benchmarks/profile_tick) and
-                            # the jaxpr auditor (repro.analysis.audit) walk
-                            # these so their phase split can never drift from
-                            # the real tick
+                            #   ...) — the six tick sub-steps step_fn composes,
+                            # each under ``jax.named_scope(name)``; the jaxpr
+                            # auditor (repro.analysis.audit) walks these so its
+                            # phase split can never drift from the real tick
     step_fn: callable       # (Consts, SimState) -> SimState — sweepable form
     step: callable          # SimState -> SimState (consts bound)
     horizon_fn: callable    # (Consts, SimState) -> i32 next-event distance
     horizon: callable       # SimState -> i32 (consts bound)
     init: callable          # () -> SimState
 
-    def run(self, max_ticks: int, seed: int = 0) -> SimState:
+    def run(self, max_ticks: int, seed: int = 0, counters: bool = False):
         """Run to completion.  ``seed`` sets the per-run hash salt
-        (RED/ECMP decorrelation) — seed 0 is the historical default."""
-        st0 = self.init()
-        if seed:
-            st0 = st0._replace(salt=jnp.asarray(seed, I32))
-        return _run_until_done(self.step_fn,
-                               self.horizon_fn if self.dims.leap else None,
-                               self.consts, st0, max_ticks,
-                               self.dims.superstep)
+        (RED/ECMP decorrelation) — seed 0 is the historical default.
+        With ``counters`` the loop also counts what it did and the call
+        returns ``(state, LoopCounters)``; the state is bit-identical."""
+        with jax.profiler.TraceAnnotation("netsim.init_state"):
+            st0 = self.init()
+            if seed:
+                st0 = st0._replace(salt=jnp.asarray(seed, I32))
+        with jax.profiler.TraceAnnotation("netsim.run_loop"):
+            return _run_until_done(self.step_fn,
+                                   self.horizon_fn if self.dims.leap else None,
+                                   self.consts, st0, max_ticks,
+                                   self.dims.superstep, counters)
 
     def run_trace(self, ticks: int, trace_flows: int = 8):
         return _run_trace(self.step, self.init(), ticks, trace_flows)
@@ -149,6 +167,11 @@ class Sim:
 
 
 def build(cfg: SimConfig, wl: Workload) -> Sim:
+    with jax.profiler.TraceAnnotation("netsim.build"):
+        return _build(cfg, wl)
+
+
+def _build(cfg: SimConfig, wl: Workload) -> Sim:
     topo, tm, dims, consts = derive(cfg, wl)
     cc_update = registry.get(cfg.algo, cfg.cc_backend)
     # fabric/transport hot-loop backends, resolved once like cc_update:
@@ -171,8 +194,9 @@ def build(cfg: SimConfig, wl: Workload) -> Sim:
 
     def step_fn(consts: Consts, st: SimState) -> SimState:
         _STEP_TRACES.hit()
-        for _, phase in phases:
-            st = phase(consts, st)
+        for name, phase in phases:
+            with jax.named_scope(name):
+                st = phase(consts, st)
         return st._replace(now=st.now + 1)
 
     def step(st: SimState) -> SimState:
@@ -219,7 +243,7 @@ def build(cfg: SimConfig, wl: Workload) -> Sim:
 # build a fresh ``init()`` per call).
 
 
-def _superstep_loop(step, cond, K, leap=None):
+def _superstep_loop(step, cond, K, leap=None, counters=False, live=None):
     """while(cond) { leap?; K x (cond ? step : id) } — cond reduced once
     per K.
 
@@ -231,25 +255,61 @@ def _superstep_loop(step, cond, K, leap=None):
     the bit-for-bit equivalence contract across K.)
 
     ``leap``, when given, runs once per superstep before the fused ticks:
-    it advances ``now`` to the next event horizon in O(1) (DESIGN.md Sec.
-    6.3).  The leap lands *at or before* the next eventful tick and the
-    leap distance is clamped to the remaining tick budget, so the gated
-    ticks that follow execute exactly the eventful ticks (plus event-free
-    ticks, which are state no-ops) of the leap-free trajectory."""
-    def tick(_, st):
-        return jax.lax.cond(cond(st), step, lambda s: s, st)
+    ``st -> (st, d)`` advances ``now`` by ``d`` to the next event horizon
+    in O(1) (DESIGN.md Sec. 6.3).  The leap lands *at or before* the next
+    eventful tick and the leap distance is clamped to the remaining tick
+    budget, so the gated ticks that follow execute exactly the eventful
+    ticks (plus event-free ticks, which are state no-ops) of the leap-free
+    trajectory.
 
-    def body(st):
+    The leap runs under ``jax.named_scope("leap")``, the exit predicate
+    and the per-tick gate under ``"loop_ctl"`` (the step names its own
+    phases).  With ``counters`` the loop carries a ``LoopCounters`` beside
+    the state and returns ``(state, counters)``; ``live`` (default
+    ``cond``) says which ticks stepped — per lane in the lane loop.  With
+    ``counters`` off the carry is the state alone."""
+    live = cond if live is None else live
+
+    def gate(st):
+        with jax.named_scope("loop_ctl"):
+            return cond(st)
+
+    def tick(_, carry):
+        st, c = carry
+        if c is not None:
+            with jax.named_scope("loop_ctl"):
+                c = c._replace(ticks_executed=c.ticks_executed
+                               + live(st).astype(I32))
+        return jax.lax.cond(gate(st), step, lambda s: s, st), c
+
+    def body(carry):
+        st, c = carry
         if leap is not None:
-            st = leap(st)
-        return jax.lax.fori_loop(0, max(K, 1), tick, st)
+            with jax.named_scope("leap"):
+                st, d = leap(st)
+            if c is not None:
+                c = c._replace(leaps=c.leaps + (d > 0).astype(I32),
+                               ticks_leapt=c.ticks_leapt + d)
+        if c is not None:
+            c = c._replace(supersteps=c.supersteps + 1)
+        return jax.lax.fori_loop(0, max(K, 1), tick, (st, c))
 
-    return lambda st: jax.lax.while_loop(cond, body, st)
+    def run(st):
+        c = None
+        if counters:
+            zero = jnp.zeros(jax.eval_shape(live, st).shape, I32)
+            c = LoopCounters(zero, zero, zero, zero)
+        st, c = jax.lax.while_loop(lambda carry: gate(carry[0]), body,
+                                   (st, c))
+        return (st, c) if counters else st
+
+    return run
 
 
 def _leap(horizon, max_ticks):
     """Single-run time leap: jump ``now`` to the next event horizon and
-    apply the closed-form Δ-tick accounting (``metrics.leap_account``).
+    apply the closed-form Δ-tick accounting (``metrics.leap_account``);
+    returns ``(state, d)``, ``d`` the distance leapt.
 
     Today's leap predicate only jumps with every queue empty, so the
     occupancy integral provably contributes 0.0 — the general Δ * Σq form
@@ -259,13 +319,14 @@ def _leap(horizon, max_ticks):
         d = jnp.minimum(horizon(st), max_ticks - st.now)
         occ = jnp.sum(st.q_size[:-1])
         return st._replace(now=st.now + d,
-                           m=metrics.leap_account(st.m, d, occ))
+                           m=metrics.leap_account(st.m, d, occ)), d
     return leap
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 4, 5), donate_argnums=(3,))
+@functools.partial(jax.jit, static_argnums=(0, 1, 4, 5, 6),
+                   donate_argnums=(3,))
 def _run_until_done(step_fn, horizon_fn, consts: Consts, state0: SimState,
-                    max_ticks: int, superstep: int) -> SimState:
+                    max_ticks: int, superstep: int, counters: bool = False):
     # ``consts`` is an argument, as in the lane loop, and not closed over:
     # closed-over scalars become literals that XLA folds into the f32
     # arithmetic (``cwnd / bdp * fd`` -> ``cwnd * c``), which rounds
@@ -276,7 +337,7 @@ def _run_until_done(step_fn, horizon_fn, consts: Consts, state0: SimState,
     step = functools.partial(step_fn, consts)
     leap = (_leap(functools.partial(horizon_fn, consts), max_ticks)
             if horizon_fn is not None else None)
-    return _superstep_loop(step, cond, superstep, leap)(state0)
+    return _superstep_loop(step, cond, superstep, leap, counters)(state0)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2, 3), donate_argnums=(1,))

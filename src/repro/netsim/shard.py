@@ -62,7 +62,8 @@ LANE_AXIS = "lanes"
 # --------------------------------------------------------------------------
 
 
-def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int):
+def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
+              counters: bool = False):
     """The ``[B]`` lane batch run loop as a pure function
     ``(consts_b, states) -> states`` (not jitted — the callers wrap it).
 
@@ -77,7 +78,8 @@ def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int):
     skip their quiescent stretches without waiting on busy lanes
     (DESIGN.md Sec. 6.3).  The superstep structure (leap once, then K
     gated ticks per while iteration) matches ``engine._superstep_loop``
-    exactly."""
+    exactly.  With ``counters`` the function returns
+    ``(states, LoopCounters)``, one count per lane."""
 
     def lane_live(st):
         return (st.now < max_ticks) & ~jnp.all(st.done)
@@ -87,38 +89,39 @@ def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int):
                             lambda s: s, st)
 
     vtick = jax.vmap(lane_tick, in_axes=(axes, 0))
+    vlive = jax.vmap(lane_live)
 
     def cond(st):
         return jnp.any((st.now < max_ticks) & ~jnp.all(st.done, axis=-1))
 
-    def run(consts_b, states: state.SimState) -> state.SimState:
+    def run(consts_b, states: state.SimState):
         leap = None
         if horizon_fn is not None:
             vhorizon = jax.vmap(horizon_fn, in_axes=(axes, 0))
-            vlive = jax.vmap(lane_live)
 
             def leap(st):
                 d = jnp.minimum(vhorizon(consts_b, st), max_ticks - st.now)
                 d = jnp.where(vlive(st), d, 0)
                 occ = jnp.sum(st.q_size[:, :-1], axis=1)
                 return st._replace(now=st.now + d,
-                                   m=metrics.leap_account(st.m, d, occ))
+                                   m=metrics.leap_account(st.m, d, occ)), d
 
         return engine._superstep_loop(lambda st: vtick(consts_b, st), cond,
-                                      superstep, leap)(states)
+                                      superstep, leap, counters,
+                                      live=vlive)(states)
 
     return run
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4),
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 7),
                    donate_argnums=(6,))
 def _run_lanes(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
-               consts_b, states: state.SimState) -> state.SimState:
+               consts_b, states: state.SimState, counters: bool = False):
     """Single-device vmap execution of :func:`lane_loop` (the historical
     ``api._run_lanes``).  ``states`` is donated; ``consts_b`` is not
     (reused across calls)."""
     return lane_loop(step_fn, horizon_fn, axes, max_ticks,
-                     superstep)(consts_b, states)
+                     superstep, counters)(consts_b, states)
 
 
 # --------------------------------------------------------------------------
@@ -181,20 +184,24 @@ def _specs(states, axes, treedef):
     return state_specs, consts_specs
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5),
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 8),
                    donate_argnums=(7,))
 def _run_lanes_sharded(step_fn, horizon_fn, axes, max_ticks: int,
                        superstep: int, mesh: Mesh, consts_b,
-                       states: state.SimState) -> state.SimState:
+                       states: state.SimState, counters: bool = False):
     """shard_map execution: each device runs :func:`lane_loop` over its
     own contiguous lane block under its own while loop.  Lane count
     must be a multiple of ``mesh.size`` (see :func:`pad_lanes`)."""
-    loop = lane_loop(step_fn, horizon_fn, axes, max_ticks, superstep)
+    loop = lane_loop(step_fn, horizon_fn, axes, max_ticks, superstep,
+                     counters)
     _, treedef = jax.tree_util.tree_flatten(consts_b)
     state_specs, consts_specs = _specs(states, axes, treedef)
+    out_specs = state_specs
+    if counters:
+        out_specs = (state_specs, engine.LoopCounters(*[P(LANE_AXIS)] * 4))
     sharded = jax.shard_map(loop, mesh=mesh,
                             in_specs=(consts_specs, state_specs),
-                            out_specs=state_specs, check_vma=False)
+                            out_specs=out_specs, check_vma=False)
     return sharded(consts_b, states)
 
 
@@ -205,7 +212,7 @@ def _run_lanes_sharded(step_fn, horizon_fn, axes, max_ticks: int,
 
 def run_lanes(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
               consts_b, states: state.SimState, mesh: Mesh | None = None,
-              ) -> state.SimState:
+              counters: bool = False):
     """Run a ``[B]`` lane batch to completion — THE batched run loop
     behind ``Study``/``Sim.run_batch``/``Sweep.run``.
 
@@ -214,14 +221,16 @@ def run_lanes(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
     device-count multiple, shards lanes (and swept consts) across the
     mesh via ``shard_map``, runs one independent loop per device, and
     gathers + slices the result back to ``[B]`` — bit-identical to the
-    vmap path, lane for lane.  ``states`` is donated either way."""
+    vmap path, lane for lane.  ``states`` is donated either way.  With
+    ``counters`` it returns ``(states, LoopCounters)``, one count per
+    lane."""
     if mesh is None or mesh.size <= 1:
         return _run_lanes(step_fn, horizon_fn, axes, max_ticks, superstep,
-                          consts_b, states)
+                          consts_b, states, counters)
     B = int(states.now.shape[0])
     states, consts_p, n_pad = pad_lanes(states, consts_b, axes, mesh.size)
     out = _run_lanes_sharded(step_fn, horizon_fn, axes, max_ticks,
-                             superstep, mesh, consts_p, states)
+                             superstep, mesh, consts_p, states, counters)
     if n_pad:
         out = jax.tree.map(lambda x: x[:B], out)
     return out
