@@ -42,7 +42,7 @@ import numpy as np  # noqa: E402
 
 from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.kernels import interpret_mode  # noqa: E402
-from repro.netsim import api, cache, scenarios, shard  # noqa: E402
+from repro.netsim import api, cache, scenarios, shard, state  # noqa: E402
 from repro.netsim.metrics import conservation_ledger  # noqa: E402
 
 PALLAS_INT = dict(fabric_backend="pallas", transport_backend="pallas")
@@ -78,7 +78,8 @@ def timed_run(sim, max_ticks: int, seed: int = 0):
 
 def kernel_calls(sim) -> int:
     """Compiled Pallas kernels in one lowered tick (0 when interpreted)."""
-    return jax.jit(sim.step).lower(sim.init()).as_text().count(
+    return jax.jit(sim.step).lower(
+        state.ring_loop_form(sim.init())).as_text().count(
         "tpu_custom_call")
 
 

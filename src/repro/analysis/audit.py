@@ -383,14 +383,15 @@ def audit_scenario(sc, backends=("jnp", "pallas"), per_phase: bool = True):
     real init state.  ``rows`` are analysis-ledger rows (op counts and
     bytes per program).
     """
-    from repro.netsim import engine
+    from repro.netsim import engine, state
 
     findings: list[Finding] = []
     rows: list[dict] = []
     for backend in backends:
         sim = engine.build(_backend_cfg(sc.cfg, backend), sc.wl)
         site_base = f"{sc.name}/{backend}"
-        st_struct = jax.eval_shape(sim.init)
+        # the phases and the step take the run loops' form of the state
+        st_struct = jax.eval_shape(lambda: state.ring_loop_form(sim.init()))
         consts = sim.consts
 
         programs = {"init": jax.make_jaxpr(sim.init)()}

@@ -63,7 +63,8 @@ from repro.kernels.ring_drain import ops as ring_drain_ops
 from repro.netsim import fabric, metrics, sender, transport
 from repro.netsim.metrics import HIST_BINS, jain_fairness, summarize  # noqa: F401 (re-export)
 from repro.netsim.state import (Consts, Dims, SimConfig, SimState,  # noqa: F401
-                                derive, init_state)
+                                derive, init_state, ring_loop_form,
+                                ring_public_form)
 from repro.netsim.topology import Topology
 from repro.netsim.units import Timing
 from repro.netsim.workloads import Workload
@@ -108,7 +109,9 @@ class Sim:
                             # each under ``jax.named_scope(name)``; the jaxpr
                             # auditor (repro.analysis.audit) walks these so its
                             # phase split can never drift from the real tick
-    step_fn: callable       # (Consts, SimState) -> SimState — sweepable form
+    step_fn: callable       # (Consts, SimState) -> SimState — sweepable form;
+                            #   phases and step take the state with its
+                            #   ring in the loop form (ring_loop_form)
     step: callable          # SimState -> SimState (consts bound)
     horizon_fn: callable    # (Consts, SimState) -> i32 next-event distance
     horizon: callable       # SimState -> i32 (consts bound)
@@ -267,7 +270,11 @@ def _superstep_loop(step, cond, K, leap=None, counters=False, live=None):
     phases).  With ``counters`` the loop carries a ``LoopCounters`` beside
     the state and returns ``(state, counters)``; ``live`` (default
     ``cond``) says which ticks stepped — per lane in the lane loop.  With
-    ``counters`` off the carry is the state alone."""
+    ``counters`` off the carry is the state alone.
+
+    ``run`` takes and returns the public state; the loop carries the
+    port-queue ring in its loop form (``state.ring_loop_form``), converted
+    once on entry and once on exit, which the step expects."""
     live = cond if live is None else live
 
     def gate(st):
@@ -295,12 +302,15 @@ def _superstep_loop(step, cond, K, leap=None, counters=False, live=None):
         return jax.lax.fori_loop(0, max(K, 1), tick, (st, c))
 
     def run(st):
+        rows, cap = st.q_fields.shape[-3:-1]
+        st = ring_loop_form(st)
         c = None
         if counters:
             zero = jnp.zeros(jax.eval_shape(live, st).shape, I32)
             c = LoopCounters(zero, zero, zero, zero)
         st, c = jax.lax.while_loop(lambda carry: gate(carry[0]), body,
                                    (st, c))
+        st = ring_public_form(st, rows, cap)
         return (st, c) if counters else st
 
     return run
@@ -357,4 +367,6 @@ def _run_trace(step, state0: SimState, ticks: int, trace_flows: int):
         )
         return st2, ys
 
-    return jax.lax.scan(body, state0, None, length=ticks)
+    rows, cap = state0.q_fields.shape[-3:-1]
+    st, ys = jax.lax.scan(body, ring_loop_form(state0), None, length=ticks)
+    return ring_public_form(st, rows, cap), ys

@@ -9,6 +9,8 @@ Both are pure ``(Dims, Consts, SimState) -> SimState``; they communicate
 with the rest of the pipeline only through ``SimState`` fields (the wire
 ring ``infl``, the delayed control rings, and the receiver ledgers).
 Routing is purely functional over the per-emitter constants in ``Consts``.
+Both take the port-queue ring ``q_fields`` in the run loops' flat form
+(``state.ring_loop_form``).
 
 ``horizon`` is the phases' next-event reduction for the engine's
 event-horizon time leaping (DESIGN.md Sec. 6.3): every delay ring keeps the
@@ -124,8 +126,8 @@ def departures(dims: Dims, consts: Consts, st: SimState) -> SimState:
         svc = True
     active = (st.q_size[:NQ] > 0) & svc
     head = st.q_head[:NQ]
-    hf = st.q_fields[qidx, head]                      # [NQ, 5]
-    d_flow, d_seq, d_ent, d_ecn, d_ts = (hf[:, i] for i in range(5))
+    # the ring in its loop form (state.ring_loop_form): one column per slot
+    d_flow, d_seq, d_ent, d_ecn, d_ts = st.q_fields[:, qidx * CAP + head]
     # RED marking at dequeue (paper Sec. 2.1 / 3.5)
     qsz = st.q_size[:NQ].astype(F32)
     pmark = jnp.clip((qsz - consts.kmin) / consts.kspan, 0.0, 1.0)
@@ -266,14 +268,15 @@ def arrivals(dims: Dims, consts: Consts, st: SimState,
                                  CAP, NQ)
     row = jnp.where(acc, edst, NQ)
     posw = jnp.where(acc, pos, 0)
-    # (indices are NOT unique: every non-accepted emitter collapses onto
-    # the write-off cell (NQ, 0), which is never read — the payload is
-    # masked to zero there so the cell stays constant and an event-free
-    # tick leaves the whole array bitwise unchanged, the property time
-    # leaping relies on)
-    q_fields = st.q_fields.at[row, posw].set(
-        jnp.where(acc[:, None],
-                  jnp.stack([e_flow, e_seq, e_ent, e_ecn, e_ts], axis=1), 0),
+    # One scatter of 5-field columns at row*CAP + posw into the ring's
+    # loop form (state.ring_loop_form).  (Indices are NOT unique: every
+    # non-accepted emitter collapses onto the write-off cell (NQ, 0), column
+    # NQ*CAP, which is never read — the payload is masked to zero there so
+    # the cell stays constant and an event-free tick leaves the whole array
+    # bitwise unchanged, the property time leaping relies on.)
+    q_fields = st.q_fields.at[:, row * CAP + posw].set(
+        jnp.where(acc[None, :],
+                  jnp.stack([e_flow, e_seq, e_ent, e_ecn, e_ts], axis=0), 0),
         mode="promise_in_bounds")
     # per-queue accepted counts come out of the fan-in groups (a dense
     # compare+reduce in the ops layer), not a segment_sum scatter

@@ -88,8 +88,9 @@ def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
         return jax.lax.cond(lane_live(st), lambda s: step_fn(c, s),
                             lambda s: s, st)
 
-    vtick = jax.vmap(lane_tick, in_axes=(axes, 0))
-    vlive = jax.vmap(lane_live)
+    st_axes = state.LANE_AXES_LOOP_FORM      # the carry is in the loop form
+    vtick = jax.vmap(lane_tick, in_axes=(axes, st_axes), out_axes=st_axes)
+    vlive = jax.vmap(lane_live, in_axes=(st_axes,))
 
     def cond(st):
         return jnp.any((st.now < max_ticks) & ~jnp.all(st.done, axis=-1))
@@ -97,7 +98,7 @@ def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
     def run(consts_b, states: state.SimState):
         leap = None
         if horizon_fn is not None:
-            vhorizon = jax.vmap(horizon_fn, in_axes=(axes, 0))
+            vhorizon = jax.vmap(horizon_fn, in_axes=(axes, st_axes))
 
             def leap(st):
                 d = jnp.minimum(vhorizon(consts_b, st), max_ticks - st.now)

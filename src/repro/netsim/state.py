@@ -254,6 +254,13 @@ class SimState(NamedTuple):
     now: jnp.ndarray                 # i32 scalar
     salt: jnp.ndarray                # i32 scalar — per-run hash decorrelation
     q_fields: jnp.ndarray            # i32 [NQ+1, CAP, 5] flow/seq/ent/ecn/ts
+                                     #   (port-queue ring; row NQ is the
+                                     #   write-off queue.  The run loops
+                                     #   carry it flat and field-major,
+                                     #   [5, (NQ+1)*CAP padded to 128],
+                                     #   slot (q, pos) at column q*CAP + pos,
+                                     #   converted on entry and exit: see
+                                     #   ring_loop_form)
     q_head: jnp.ndarray              # i32 [NQ+1]
     q_size: jnp.ndarray              # i32 [NQ+1]
     infl: jnp.ndarray                # i32 [L, NE, 7] valid/dstq/flow/seq/ent/ecn/ts
@@ -542,6 +549,48 @@ _INIT_TRACES = _trace_counter("state.init")
 # Sentinel "no event in sight" horizon (i32-safe; run loops clamp it to the
 # remaining tick budget before applying a leap).
 HORIZON_INF = 1 << 30
+
+
+# Column count granule of the ring's loop form: the TPU's lane width.
+RING_COLS_ALIGN = 128
+
+
+def ring_loop_form(st: SimState) -> SimState:
+    """``st`` with the port-queue ring in its loop form: ``q_fields``
+    ``[..., NQ+1, CAP, 5]`` -> flat and field-major ``[5, ..., C]``, slot
+    ``(q, pos)`` at column ``q*CAP + pos`` (leading lane axes kept, after
+    the fields).  ``C`` is ``(NQ+1)*CAP`` rounded up to a multiple of
+    ``RING_COLS_ALIGN``; the pad columns stay zero and are never read.
+
+    The tick's phases read and write the ring in this form only: the
+    departures head read is a gather of columns ``qidx*CAP + head``, the
+    arrivals write a scatter at ``row*CAP + pos``, one index on one layout
+    each.  Indexing the public form by ``(q, pos)`` made XLA:TPU relayout
+    the whole ring three times a tick.  In a lane batch ``[5, B, C]`` XLA
+    merges the lane and column axes into one index, which is free only
+    when ``C`` fills whole lane tiles, hence the pad.  The run loops convert
+    once per run, on entry, and :func:`ring_public_form` converts back on
+    exit, so every state a caller sees is in the public form."""
+    q = jnp.moveaxis(st.q_fields, -1, 0)
+    q = q.reshape(q.shape[:-2] + (-1,))
+    pad = (-q.shape[-1]) % RING_COLS_ALIGN
+    return st._replace(q_fields=jnp.pad(q, [(0, 0)] * (q.ndim - 1)
+                                        + [(0, pad)]))
+
+
+# vmap axes of a lane batch in the loop form: the ring's lanes follow its
+# fields axis, every other leaf's lanes lead
+LANE_AXES_LOOP_FORM = SimState(*[0] * len(SimState._fields))._replace(
+    q_fields=1)
+
+
+def ring_public_form(st: SimState, rows: int, cap: int) -> SimState:
+    """Inverse of :func:`ring_loop_form` for a ring of ``rows`` (NQ+1)
+    queues of ``cap`` slots: ``q_fields`` ``[5, ..., C]`` ->
+    ``[..., rows, cap, 5]``."""
+    q = st.q_fields[..., :rows * cap]
+    q = q.reshape(q.shape[:-1] + (rows, cap))
+    return st._replace(q_fields=jnp.moveaxis(q, 0, -1))
 
 
 def init_state(dims: Dims, consts: Consts) -> SimState:

@@ -26,7 +26,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.netsim import workloads
+from repro.netsim import state, workloads
 from repro.netsim.engine import SimConfig, build
 from repro.netsim.faults import FaultEvent, FaultSchedule, Flap
 from repro.netsim.metrics import conservation_ledger
@@ -94,7 +94,7 @@ def check_conservation(seed: int, ticks: int = 400) -> None:
     sim = build(SimConfig(link=LINK, tree=TREE3, faults=sched,
                           **_recovery_knobs(seed)), wl)
     step = jax.jit(sim.step)
-    s = sim.init()
+    s = state.ring_loop_form(sim.init())
     for t in range(ticks):
         s = step(s)
         sent, accounted = conservation_ledger(sim.dims, s)

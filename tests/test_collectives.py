@@ -115,7 +115,8 @@ def _run_dag(wl: Workload, ticks: int = 400):
     cfg, sim, trace = _dag_rig()
     _, _, dims, consts = state.derive(cfg, wl)
     assert dims == sim.dims, "fixed traffic pattern must freeze Dims"
-    fin, (gp, nseq) = trace(consts, state.init_state(dims, consts), ticks)
+    fin, (gp, nseq) = trace(
+        consts, state.ring_loop_form(state.init_state(dims, consts)), ticks)
     return np.asarray(gp), np.asarray(nseq), jax.device_get(fin)
 
 

@@ -10,7 +10,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.netsim import api, engine, shard, workloads
+from repro.netsim import api, engine, shard, state, workloads
 from repro.netsim.engine import SimConfig, build
 from repro.netsim.units import FatTreeConfig, LinkConfig
 
@@ -102,18 +102,19 @@ def test_lowered_loop_names_every_scope():
 
 
 def _while_carry(sim, counters):
-    """The run loop's while carry as (state leaves it takes, other
-    values); the leaves the loop never changes travel as constants."""
-    state = sim.init()
+    """The run loop's while carry as (state leaves it takes as passed,
+    other values); the leaves the loop never changes travel as constants,
+    and the port-queue ring travels in its loop form."""
+    st = sim.init()
     closed = jax.make_jaxpr(
         lambda c, s: engine._run_until_done(
             sim.step_fn, sim.horizon_fn, c, s, MAX_TICKS,
-            sim.dims.superstep, counters))(sim.consts, state)
+            sim.dims.superstep, counters))(sim.consts, st)
     (jit,) = closed.eqns
     inner = jit.params["jaxpr"].jaxpr
     (loop,) = [e for e in inner.eqns if e.primitive.name == "while"]
     n_consts = loop.params["cond_nconsts"] + loop.params["body_nconsts"]
-    state_vars = inner.invars[-len(jax.tree.leaves(state)):]
+    state_vars = inner.invars[-len(jax.tree.leaves(st)):]
     carry = loop.invars[n_consts:]
     taken = [v for v in carry if v in state_vars]
     return taken, [v.aval for v in carry if v not in state_vars]
@@ -121,11 +122,14 @@ def _while_carry(sim, counters):
 
 def test_loop_carry_holds_the_state_alone_without_counters():
     sim = _sim(True, 0)
+    ring = jax.eval_shape(state.ring_loop_form,
+                          jax.eval_shape(sim.init)).q_fields
+    ring = [(ring.shape, ring.dtype)]
     taken, other = _while_carry(sim, False)
-    assert taken and other == []
+    assert taken and [(a.shape, a.dtype) for a in other] == ring
     taken_c, other_c = _while_carry(sim, True)
     assert len(taken_c) == len(taken)
-    assert [(a.shape, a.dtype) for a in other_c] == [((), np.int32)] * 4
+    assert [(a.shape, a.dtype) for a in other_c] == ring + [((), np.int32)] * 4
 
 
 def test_api_run_reports_counters():

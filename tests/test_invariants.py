@@ -20,7 +20,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.netsim import workloads
+from repro.netsim import state, workloads
 from repro.netsim.engine import SimConfig, build
 from repro.netsim.metrics import conservation_ledger
 from repro.netsim.units import FatTreeConfig, LinkConfig
@@ -34,14 +34,14 @@ TREE3 = FatTreeConfig(racks=4, nodes_per_rack=2, uplinks=2,
 def _check_conservation(tree, wl, ticks, **cfg_kw):
     sim = build(SimConfig(link=LINK, tree=tree, **cfg_kw), wl)
     step = jax.jit(sim.step)
-    st = sim.init()
+    st = state.ring_loop_form(sim.init())
     for t in range(ticks):
         st = step(st)
         sent, accounted = conservation_ledger(sim.dims, st)
         assert sent == accounted, (
             f"tick {t + 1}: {sent} packets sent but {accounted} accounted "
             f"(delivered+trimmed+dropped+blackholed+queued+on-wire)")
-    return st
+    return state.ring_public_form(st, sim.dims.NQ + 1, sim.dims.CAP)
 
 
 @pytest.mark.parametrize("trimming", [True, False],
