@@ -82,14 +82,17 @@ class LoopCounters(NamedTuple):
     """What a run loop did, counted on the device beside the state (never
     a ``SimState`` leaf): ``ticks_executed`` gated ticks that stepped,
     ``supersteps`` while iterations, ``leaps`` leaps with a distance > 0,
-    ``ticks_leapt`` the sum of those distances.  Scalars for a single run;
-    in the lane loop one entry per lane (``supersteps`` counts the
-    iterations of the loop that ran the lane)."""
+    ``ticks_leapt`` the sum of those distances, ``flow_ticks_live`` the
+    sum over the ticks that stepped of the flows ``sender.activated``
+    holds live at the tick's start (``Sim.live_flows``).  Scalars for a
+    single run; in the lane loop one entry per lane (``supersteps``
+    counts the iterations of the loop that ran the lane)."""
 
     ticks_executed: jax.Array
     supersteps: jax.Array
     leaps: jax.Array
     ticks_leapt: jax.Array
+    flow_ticks_live: jax.Array
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,6 +119,8 @@ class Sim:
     horizon_fn: callable    # (Consts, SimState) -> i32 next-event distance
     horizon: callable       # SimState -> i32 (consts bound)
     init: callable          # () -> SimState
+    live_flows: callable    # (Consts, SimState) -> i32 flows that
+                            #   sender.activated holds live (counters)
 
     def run(self, max_ticks: int, seed: int = 0, counters: bool = False):
         """Run to completion.  ``seed`` sets the per-run hash salt
@@ -130,7 +135,8 @@ class Sim:
             return _run_until_done(self.step_fn,
                                    self.horizon_fn if self.dims.leap else None,
                                    self.consts, st0, max_ticks,
-                                   self.dims.superstep, counters)
+                                   self.dims.superstep, counters,
+                                   self.live_flows if counters else None)
 
     def run_trace(self, ticks: int, trace_flows: int = 8):
         return _run_trace(self.step, self.init(), ticks, trace_flows)
@@ -218,10 +224,13 @@ def _build(cfg: SimConfig, wl: Workload) -> Sim:
     def init() -> SimState:
         return init_state(dims, consts)
 
+    def live_flows(consts: Consts, st: SimState):
+        return jnp.sum(sender.activated(dims, consts, st).astype(I32))
+
     return Sim(cfg=cfg, topo=topo, timing=tm, wl=wl, cc_params=consts.cc,
                lb_params=consts.lb, dims=dims, consts=consts, phases=phases,
                step_fn=step_fn, step=step, horizon_fn=horizon_fn,
-               horizon=horizon, init=init)
+               horizon=horizon, init=init, live_flows=live_flows)
 
 
 # --------------------------------------------------------------------------
@@ -246,7 +255,8 @@ def _build(cfg: SimConfig, wl: Workload) -> Sim:
 # build a fresh ``init()`` per call).
 
 
-def _superstep_loop(step, cond, K, leap=None, counters=False, live=None):
+def _superstep_loop(step, cond, K, leap=None, counters=False, live=None,
+                    live_flows=None):
     """while(cond) { leap?; K x (cond ? step : id) } — cond reduced once
     per K.
 
@@ -269,13 +279,17 @@ def _superstep_loop(step, cond, K, leap=None, counters=False, live=None):
     and the per-tick gate under ``"loop_ctl"`` (the step names its own
     phases).  With ``counters`` the loop carries a ``LoopCounters`` beside
     the state and returns ``(state, counters)``; ``live`` (default
-    ``cond``) says which ticks stepped — per lane in the lane loop.  With
-    ``counters`` off the carry is the state alone.
+    ``cond``) says which ticks stepped and ``live_flows`` (``st -> i32``,
+    required with ``counters``) how many flows are live at a tick's start
+    — both per lane in the lane loop.  With ``counters`` off the carry is
+    the state alone and ``live_flows`` is never traced.
 
     ``run`` takes and returns the public state; the loop carries the
     port-queue ring in its loop form (``state.ring_loop_form``), converted
     once on entry and once on exit, which the step expects."""
     live = cond if live is None else live
+    if counters and live_flows is None:
+        raise ValueError("a counting loop needs live_flows")
 
     def gate(st):
         with jax.named_scope("loop_ctl"):
@@ -285,8 +299,11 @@ def _superstep_loop(step, cond, K, leap=None, counters=False, live=None):
         st, c = carry
         if c is not None:
             with jax.named_scope("loop_ctl"):
-                c = c._replace(ticks_executed=c.ticks_executed
-                               + live(st).astype(I32))
+                stepped = live(st)
+                c = c._replace(
+                    ticks_executed=c.ticks_executed + stepped.astype(I32),
+                    flow_ticks_live=c.flow_ticks_live
+                    + jnp.where(stepped, live_flows(st), 0))
         return jax.lax.cond(gate(st), step, lambda s: s, st), c
 
     def body(carry):
@@ -307,7 +324,7 @@ def _superstep_loop(step, cond, K, leap=None, counters=False, live=None):
         c = None
         if counters:
             zero = jnp.zeros(jax.eval_shape(live, st).shape, I32)
-            c = LoopCounters(zero, zero, zero, zero)
+            c = LoopCounters(*[zero] * len(LoopCounters._fields))
         st, c = jax.lax.while_loop(lambda carry: gate(carry[0]), body,
                                    (st, c))
         st = ring_public_form(st, rows, cap)
@@ -333,10 +350,11 @@ def _leap(horizon, max_ticks):
     return leap
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 4, 5, 6),
+@functools.partial(jax.jit, static_argnums=(0, 1, 4, 5, 6, 7),
                    donate_argnums=(3,))
 def _run_until_done(step_fn, horizon_fn, consts: Consts, state0: SimState,
-                    max_ticks: int, superstep: int, counters: bool = False):
+                    max_ticks: int, superstep: int, counters: bool = False,
+                    live_flows=None):
     # ``consts`` is an argument, as in the lane loop, and not closed over:
     # closed-over scalars become literals that XLA folds into the f32
     # arithmetic (``cwnd / bdp * fd`` -> ``cwnd * c``), which rounds
@@ -347,7 +365,10 @@ def _run_until_done(step_fn, horizon_fn, consts: Consts, state0: SimState,
     step = functools.partial(step_fn, consts)
     leap = (_leap(functools.partial(horizon_fn, consts), max_ticks)
             if horizon_fn is not None else None)
-    return _superstep_loop(step, cond, superstep, leap, counters)(state0)
+    count = (functools.partial(live_flows, consts)
+             if live_flows is not None else None)
+    return _superstep_loop(step, cond, superstep, leap, counters,
+                           live_flows=count)(state0)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2, 3), donate_argnums=(1,))

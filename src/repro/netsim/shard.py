@@ -63,7 +63,7 @@ LANE_AXIS = "lanes"
 
 
 def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
-              counters: bool = False):
+              counters: bool = False, live_flows=None):
     """The ``[B]`` lane batch run loop as a pure function
     ``(consts_b, states) -> states`` (not jitted — the callers wrap it).
 
@@ -79,7 +79,9 @@ def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
     (DESIGN.md Sec. 6.3).  The superstep structure (leap once, then K
     gated ticks per while iteration) matches ``engine._superstep_loop``
     exactly.  With ``counters`` the function returns
-    ``(states, LoopCounters)``, one count per lane."""
+    ``(states, LoopCounters)``, one count per lane; ``live_flows`` (the
+    ``Sim``'s, ``(Consts, SimState) -> i32``) counts each lane's live
+    flows."""
 
     def lane_live(st):
         return (st.now < max_ticks) & ~jnp.all(st.done)
@@ -96,6 +98,13 @@ def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
         return jnp.any((st.now < max_ticks) & ~jnp.all(st.done, axis=-1))
 
     def run(consts_b, states: state.SimState):
+        count = None
+        if live_flows is not None:
+            vcount = jax.vmap(live_flows, in_axes=(axes, st_axes))
+
+            def count(st):
+                return vcount(consts_b, st)
+
         leap = None
         if horizon_fn is not None:
             vhorizon = jax.vmap(horizon_fn, in_axes=(axes, st_axes))
@@ -109,20 +118,21 @@ def lane_loop(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
 
         return engine._superstep_loop(lambda st: vtick(consts_b, st), cond,
                                       superstep, leap, counters,
-                                      live=vlive)(states)
+                                      live=vlive, live_flows=count)(states)
 
     return run
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 7),
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 7, 8),
                    donate_argnums=(6,))
 def _run_lanes(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
-               consts_b, states: state.SimState, counters: bool = False):
+               consts_b, states: state.SimState, counters: bool = False,
+               live_flows=None):
     """Single-device vmap execution of :func:`lane_loop` (the historical
     ``api._run_lanes``).  ``states`` is donated; ``consts_b`` is not
     (reused across calls)."""
     return lane_loop(step_fn, horizon_fn, axes, max_ticks,
-                     superstep, counters)(consts_b, states)
+                     superstep, counters, live_flows)(consts_b, states)
 
 
 # --------------------------------------------------------------------------
@@ -185,21 +195,23 @@ def _specs(states, axes, treedef):
     return state_specs, consts_specs
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 8),
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 8, 9),
                    donate_argnums=(7,))
 def _run_lanes_sharded(step_fn, horizon_fn, axes, max_ticks: int,
                        superstep: int, mesh: Mesh, consts_b,
-                       states: state.SimState, counters: bool = False):
+                       states: state.SimState, counters: bool = False,
+                       live_flows=None):
     """shard_map execution: each device runs :func:`lane_loop` over its
     own contiguous lane block under its own while loop.  Lane count
     must be a multiple of ``mesh.size`` (see :func:`pad_lanes`)."""
     loop = lane_loop(step_fn, horizon_fn, axes, max_ticks, superstep,
-                     counters)
+                     counters, live_flows)
     _, treedef = jax.tree_util.tree_flatten(consts_b)
     state_specs, consts_specs = _specs(states, axes, treedef)
     out_specs = state_specs
     if counters:
-        out_specs = (state_specs, engine.LoopCounters(*[P(LANE_AXIS)] * 4))
+        out_specs = (state_specs, engine.LoopCounters(
+            *[P(LANE_AXIS)] * len(engine.LoopCounters._fields)))
     sharded = jax.shard_map(loop, mesh=mesh,
                             in_specs=(consts_specs, state_specs),
                             out_specs=out_specs, check_vma=False)
@@ -213,7 +225,7 @@ def _run_lanes_sharded(step_fn, horizon_fn, axes, max_ticks: int,
 
 def run_lanes(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
               consts_b, states: state.SimState, mesh: Mesh | None = None,
-              counters: bool = False):
+              counters: bool = False, live_flows=None):
     """Run a ``[B]`` lane batch to completion — THE batched run loop
     behind ``Study``/``Sim.run_batch``/``Sweep.run``.
 
@@ -224,14 +236,15 @@ def run_lanes(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
     gathers + slices the result back to ``[B]`` — bit-identical to the
     vmap path, lane for lane.  ``states`` is donated either way.  With
     ``counters`` it returns ``(states, LoopCounters)``, one count per
-    lane."""
+    lane, and needs the ``Sim``'s ``live_flows``."""
     if mesh is None or mesh.size <= 1:
         return _run_lanes(step_fn, horizon_fn, axes, max_ticks, superstep,
-                          consts_b, states, counters)
+                          consts_b, states, counters, live_flows)
     B = int(states.now.shape[0])
     states, consts_p, n_pad = pad_lanes(states, consts_b, axes, mesh.size)
     out = _run_lanes_sharded(step_fn, horizon_fn, axes, max_ticks,
-                             superstep, mesh, consts_p, states, counters)
+                             superstep, mesh, consts_p, states, counters,
+                             live_flows)
     if n_pad:
         out = jax.tree.map(lambda x: x[:B], out)
     return out

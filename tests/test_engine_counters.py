@@ -1,8 +1,11 @@
 """Run-loop instrumentation: the loop counters change nothing in the final
-state and add up to ``now``; the lowered loop names the tick's phases and
-the loop's own control; with counters off the loop carries the state
-alone; the program's host spans land in a profiler trace."""
+state and add up to ``now``; the live-flow count equals a recount on the
+host, tick by tick; the lowered loop names the tick's phases and the
+loop's own control; with counters off the loop carries the state alone
+and never counts live flows; the program's host spans land in a profiler
+trace."""
 
+import dataclasses
 import glob
 import os
 
@@ -10,7 +13,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.netsim import api, engine, shard, state, workloads
+from repro.netsim import api, engine, scenarios, sender, shard, state, \
+    workloads
 from repro.netsim.engine import SimConfig, build
 from repro.netsim.units import FatTreeConfig, LinkConfig
 
@@ -46,6 +50,8 @@ def _check_counts(c, now, leap, superstep):
         np.testing.assert_array_equal(executed, now)
         assert not np.any(leapt) and not np.any(np.asarray(c.leaps))
     assert np.all(np.asarray(c.supersteps) * superstep >= executed)
+    live = np.asarray(c.flow_ticks_live)
+    assert np.all(live > 0) and np.all(live <= executed * SPARSE.n_flows)
 
 
 @pytest.mark.parametrize("leap", [False, True], ids=["leap_off", "leap_on"])
@@ -73,13 +79,15 @@ def test_lane_loop_counters_leave_state_bit_identical(path, leap, superstep):
         states = jax.tree.map(
             lambda x: jax.numpy.broadcast_to(x[None], (3,) + x.shape), base)
         states = states._replace(salt=jax.numpy.arange(3, dtype=np.int32))
+        live_flows = sim.live_flows if counters else None
         if path == "vmap":
             return shard.run_lanes(sim.step_fn, horizon, axes, MAX_TICKS, K,
-                                   sim.consts, states, counters=counters)
+                                   sim.consts, states, counters=counters,
+                                   live_flows=live_flows)
         mesh = shard.lane_mesh(jax.devices()[:1])
         return shard._run_lanes_sharded(sim.step_fn, horizon, axes,
                                         MAX_TICKS, K, mesh, sim.consts,
-                                        states, counters)
+                                        states, counters, live_flows)
 
     st = lanes(False)
     st_c, c = lanes(True)
@@ -88,10 +96,91 @@ def test_lane_loop_counters_leave_state_bit_identical(path, leap, superstep):
     _check_counts(c, np.asarray(st_c.now), leap, K)
 
 
-def _lower(sim, counters=False):
+def _recount(sim, max_ticks, salt):
+    """``flow_ticks_live`` recounted on the host: the run loop's leaps,
+    supersteps and per-tick gate stepped by hand, one jitted tick at a
+    time, summing ``sender.activated`` over the ticks that step; returns
+    the sum and the final state."""
+    dims = sim.dims
+
+    def cond(st):
+        return bool(st.now < max_ticks) and not bool(np.all(st.done))
+
+    live = jax.jit(lambda st: sender.activated(dims, sim.consts, st))
+    step = jax.jit(sim.step)
+    leap = jax.jit(engine._leap(sim.horizon, max_ticks))
+    st = state.ring_loop_form(sim.init()._replace(
+        salt=jax.numpy.asarray(salt, np.int32)))
+    total = 0
+    while cond(st):
+        if dims.leap:
+            st, _ = leap(st)
+        for _ in range(max(dims.superstep, 1)):
+            if cond(st):
+                total += int(np.sum(np.asarray(live(st))))
+                st = step(st)
+    rows, cap = sim.init().q_fields.shape[:2]
+    return total, state.ring_public_form(st, rows, cap)
+
+
+RING = scenarios.scenario("tiny_allreduce_ring")
+
+
+@pytest.mark.parametrize("leap", [False, True], ids=["leap_off", "leap_on"])
+@pytest.mark.parametrize("superstep", [1, 0], ids=["k1", "k_auto"])
+def test_flow_ticks_live_equals_a_host_recount(leap, superstep):
+    sim = RING.with_(leap=leap, superstep=superstep).build()
+    assert sim.dims.D == 1
+    want, st_host = _recount(sim, RING.max_ticks, 5)
+    st, c = sim.run(RING.max_ticks, seed=5, counters=True)
+    _assert_state_equal(st_host, st)
+    assert int(c.flow_ticks_live) == want
+    # the ring's 8 ranks send one chunk at a time each, of 112 flows
+    assert 0 < want <= 8 * int(c.ticks_executed)
+
+
+def test_flow_ticks_live_equals_a_host_recount_per_study_lane():
+    study = api.study(RING, seeds=(0, 1))
+    sim = study.sim
+    horizon = sim.horizon_fn if sim.dims.leap else None
+    st, c = shard.run_lanes(sim.step_fn, horizon, study.axes,
+                            RING.max_ticks, sim.dims.superstep,
+                            study.consts_b, study.init(), counters=True,
+                            live_flows=sim.live_flows)
+    assert np.asarray(c.flow_ticks_live).shape == (2,)
+    for lane, salt in enumerate(study.salts):
+        want, st_host = _recount(sim, RING.max_ticks, salt)
+        assert int(c.flow_ticks_live[lane]) == want
+        _assert_state_equal(st_host, jax.tree.map(lambda x: x[lane], st))
+
+
+def test_counting_needs_the_live_flow_count():
+    sim = _sim(True, 0)
+    with pytest.raises(ValueError, match="live_flows"):
+        engine._run_until_done(sim.step_fn, sim.horizon_fn, sim.consts,
+                               sim.init(), MAX_TICKS, sim.dims.superstep,
+                               True)
+
+
+def _lower(sim, counters=False, live_flows=None):
     return engine._run_until_done.lower(
         sim.step_fn, sim.horizon_fn if sim.dims.leap else None, sim.consts,
-        sim.init(), MAX_TICKS, sim.dims.superstep, counters)
+        sim.init(), MAX_TICKS, sim.dims.superstep, counters, live_flows)
+
+
+def _never(consts, st):
+    raise AssertionError("live flows counted with counters off")
+
+
+@pytest.mark.parametrize("scenario", ["sparse", "tiny_allreduce_ring"])
+def test_loop_without_counters_never_counts_live_flows(scenario):
+    sim = _sim(True, 0) if scenario == "sparse" else RING.build()
+    plain = _lower(sim).as_text()
+    assert _lower(sim, False, _never).as_text() == plain
+    # Sim.run passes its count only to a counting loop
+    never = dataclasses.replace(sim, live_flows=_never)
+    want = sim.run(MAX_TICKS, seed=2)
+    _assert_state_equal(never.run(MAX_TICKS, seed=2), want)
 
 
 def test_lowered_loop_names_every_scope():
@@ -109,7 +198,8 @@ def _while_carry(sim, counters):
     closed = jax.make_jaxpr(
         lambda c, s: engine._run_until_done(
             sim.step_fn, sim.horizon_fn, c, s, MAX_TICKS,
-            sim.dims.superstep, counters))(sim.consts, st)
+            sim.dims.superstep, counters,
+            sim.live_flows if counters else None))(sim.consts, st)
     (jit,) = closed.eqns
     inner = jit.params["jaxpr"].jaxpr
     (loop,) = [e for e in inner.eqns if e.primitive.name == "while"]
@@ -129,7 +219,7 @@ def test_loop_carry_holds_the_state_alone_without_counters():
     assert taken and [(a.shape, a.dtype) for a in other] == ring
     taken_c, other_c = _while_carry(sim, True)
     assert len(taken_c) == len(taken)
-    assert [(a.shape, a.dtype) for a in other_c] == ring + [((), np.int32)] * 4
+    assert [(a.shape, a.dtype) for a in other_c] == ring + [((), np.int32)] * 5
 
 
 def test_api_run_reports_counters():
