@@ -122,6 +122,14 @@ class Sim:
     live_flows: callable    # (Consts, SimState) -> i32 flows that
                             #   sender.activated holds live (counters)
 
+    @property
+    def arb_fill(self) -> float:
+        """Share of ``sender.sends``' round-robin table, ``[NS, FMAX]``,
+        whose cells hold a flow: ``NF / (NS * FMAX)``.  ``dims.arb_map``
+        says how the flags fill it."""
+        d = self.dims
+        return d.NF / (d.NS * d.FMAX)
+
     def run(self, max_ticks: int, seed: int = 0, counters: bool = False):
         """Run to completion.  ``seed`` sets the per-run hash salt
         (RED/ECMP decorrelation) — seed 0 is the historical default.

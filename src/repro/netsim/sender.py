@@ -125,6 +125,45 @@ def admission(dims: Dims, consts: Consts, st: SimState):
     return elig, has_retx, seq_emit, nsize
 
 
+def arbitrate(dims: Dims, consts: Consts, elig, rr, arb):
+    """Per-sender round-robin pick of one eligible flow (``FMAX > 1``).
+
+    Only the ``NS`` hosts that own flows arbitrate, over their rows
+    ``flows_of_c`` of the flow table (``state.derive``): a host without
+    flows would have an all-padding row, which picks nothing and leaves
+    its cursor as it was.  Where the rows are a step-major block of the
+    flow ids (``Dims.arb_map`` ``"dense"``), the flags are reshaped into
+    the table instead of gathered, and a flow's emit flag is its cell of
+    the one-hot pick.
+
+    ``elig`` is bool [NF], ``rr`` the [N] cursors.  Returns ``(has, sel,
+    sflow, rr, emit)`` on the N NIC rows: whether the host sends, the
+    picked column, the picked flow (NF = none), the advanced cursors, and
+    bool [NF] whether each flow emits.
+    """
+    NF, FMAX, NS, dense = dims.NF, dims.FMAX, dims.NS, dims.arb_map == "dense"
+    tbl = consts.flows_of_c
+    if dense:
+        E = elig.reshape(FMAX, NS).T
+    else:
+        E = jnp.pad(elig, (0, 1))[tbl]                           # [NS, FMAX]
+    has_c, sel_c = arb(E, rr[consts.senders], FMAX)
+    sflow_c = jnp.where(has_c, tbl[jnp.arange(NS, dtype=I32), sel_c], NF)
+    # back onto the N NIC rows
+    sflow = jnp.pad(sflow_c, (0, 1), constant_values=NF)[consts.row_of]
+    sel = jnp.pad(sel_c, (0, 1))[consts.row_of]
+    has = sflow < NF
+    rr = jnp.where(has, (sel.astype(I32) + 1) % FMAX, rr)
+    if dense:
+        pick = has_c[:, None] & \
+            (jnp.arange(FMAX, dtype=I32)[None, :] == sel_c[:, None])
+        emit = pick.T.reshape(NF)
+    else:
+        # flow f emits iff its own sender selected it (gather, not scatter)
+        emit = sflow[consts.src] == consts.flow_ids
+    return has, sel, sflow, rr, emit
+
+
 def sends(dims: Dims, consts: Consts, st: SimState, arb) -> SimState:
     """Phase 5: one packet per NIC per tick, arbitration + admission.
 
@@ -152,14 +191,11 @@ def sends(dims: Dims, consts: Consts, st: SimState, arb) -> SimState:
         has_s = jnp.pad(elig, (0, 1))[consts.flows_of[:, 0]]
         sflow = jnp.where(has_s, consts.flows_of[:, 0], NF)
         rr_send = st.rr_send
+        # flow f emits iff its own sender selected it (gather, not scatter)
+        emit_mask = sflow[consts.src] == flow_ids
     else:
-        E = jnp.pad(elig, (0, 1))[consts.flows_of]               # [N, FMAX]
-        has_s, sel = arb(E, st.rr_send, FMAX)
-        sflow = jnp.where(has_s, consts.flows_of[consts.node_ids, sel], NF)
-        rr_send = jnp.where(has_s, (sel.astype(I32) + 1) % FMAX, st.rr_send)
-
-    # flow f emits iff its own sender selected it (gather, not scatter)
-    emit_mask = sflow[consts.src] == flow_ids
+        has_s, _, sflow, rr_send, emit_mask = arbitrate(
+            dims, consts, elig, st.rr_send, arb)
     lb, entropy = reps.on_send(dims.lb_mode, consts.lb, st.lb, emit_mask,
                                seq_emit, flow_ids, t)
     first_q = route_first_hop(dims, consts, entropy)

@@ -113,6 +113,13 @@ class Dims(NamedTuple):
     R: int          # control-return ring length
     MAXW: int       # receiver dedupe bitmap words
     FMAX: int       # max flows per sender
+    NS: int         # hosts that own at least one flow (the rows of
+                    # sender.sends' round-robin table)
+    arb_map: str    # how sender.sends fills that table from the flags:
+                    # "identity" (FMAX == 1, nothing to arbitrate),
+                    # "gather" through flows_of_c, or "dense", a reshape
+                    # where flows_of_c is the step-major block
+                    # arange(NF).reshape(FMAX, NS).T
     FRMAX: int      # max flows per receiver
     P: int          # racks
     U: int          # T0 uplinks per rack (spines / aggs-per-pod)
@@ -162,6 +169,10 @@ class Consts(NamedTuple):
                                  #   ack ring layout requires it constant)
     flows_of: jnp.ndarray        # i32 [N, FMAX] per-sender flow table
     slot_of: jnp.ndarray         # i32 [NF] flow's column in flows_of[src]
+    senders: jnp.ndarray         # i32 [NS] hosts that own a flow, ascending
+    flows_of_c: jnp.ndarray      # i32 [NS, FMAX] = flows_of[senders]
+    row_of: jnp.ndarray          # i32 [N] host's row in flows_of_c (NS =
+                                 #   the host sends nothing)
     flows_by_recv: jnp.ndarray   # i32 [N, FRMAX]
     lat_q: jnp.ndarray           # i32 [NE] post-departure wire latency
     # -- compiled fault schedule (faults.compile_tables; times relative to
@@ -374,6 +385,20 @@ def derive(cfg: SimConfig, wl: Workload):
         flows_of[s, cnt[s]] = f
         slot_of[f] = cnt[s]
         cnt[s] += 1
+    # only the NS hosts that own a flow arbitrate (sender.sends): their
+    # rows of flows_of, in the same order, and each host's row among them
+    senders = np.flatnonzero(np.bincount(wl.src, minlength=N))
+    NS = len(senders)
+    flows_of_c = flows_of[senders]
+    row_of = np.full(N, NS, np.int32)
+    row_of[senders] = np.arange(NS)
+    if FMAX == 1:
+        arb_map = "identity"
+    elif NS * FMAX == NF and np.array_equal(
+            flows_of_c, np.arange(NF, dtype=np.int32).reshape(FMAX, NS).T):
+        arb_map = "dense"
+    else:
+        arb_map = "gather"
     flows_by_recv = np.full((N, FRMAX), NF, np.int32)
     cnt = np.zeros(N, np.int64)
     for f in range(NF):
@@ -461,7 +486,8 @@ def derive(cfg: SimConfig, wl: Workload):
 
     dims = Dims(
         N=N, NQ=NQ, NE=NE, NF=NF, CAP=CAP, W=W, WW=WW, L=L, R=R,
-        MAXW=MAXW, FMAX=FMAX, FRMAX=FRMAX, P=P, U=U, M=M, QE=QE,
+        MAXW=MAXW, FMAX=FMAX, NS=NS, arb_map=arb_map, FRMAX=FRMAX,
+        P=P, U=U, M=M, QE=QE,
         tiers=tree.tiers,
         window=window, D=D, mtu=int(MTU), brtt_inter=int(tm.brtt_inter),
         bdp_bytes=bdp, superstep=superstep, leap=leap,
@@ -483,6 +509,9 @@ def derive(cfg: SimConfig, wl: Workload):
         ret=ret_f,
         flows_of=jnp.asarray(flows_of),
         slot_of=jnp.asarray(slot_of),
+        senders=jnp.asarray(senders, I32),
+        flows_of_c=jnp.asarray(flows_of_c),
+        row_of=jnp.asarray(row_of),
         flows_by_recv=jnp.asarray(flows_by_recv),
         lat_q=jnp.asarray(lat_q),
         ft_time=jnp.asarray(cf.ft_time),

@@ -11,7 +11,9 @@ check that the program holds the compiled kernel.
 
 The run loops (``engine._run_until_done`` at the benchmark cell's widths,
 ``shard._run_lanes`` with 8 lanes) are compiled whole the same way, and
-their while bodies are searched for a relayout of the ring ``q_fields``.
+their while bodies are searched for a relayout of the ring ``q_fields``,
+and, at the widths of a 64-rank ring all-reduce on 1024 hosts, for a
+sender arbitration table with a row for every host.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library.
@@ -31,7 +33,7 @@ from repro.kernels.cc_update import kernel as cc_kernel
 from repro.kernels.cc_update import ref as cc_ref
 from repro.kernels.enqueue_arb import kernel as arb_kernel
 from repro.kernels.ring_drain import kernel as drain_kernel
-from repro.netsim import api, engine, scenarios, shard, state
+from repro.netsim import api, collectives, engine, scenarios, shard, state
 from repro.netsim.units import LinkConfig
 
 I32, F32 = jnp.int32, jnp.float32
@@ -254,3 +256,41 @@ def test_lane_loop_moves_no_whole_ring_per_tick(one_chip,
         _structs(jax.eval_shape(sim.init), one_chip, (lanes,))
     ).compile().as_text()
     assert ring_relayouts(hlo, _ring_elems(sim, lanes)) == []
+
+
+# --------------------------------------------------------------------------
+# the run loop: sender arbitration over the hosts that own flows alone
+# --------------------------------------------------------------------------
+
+
+def gathers(hlo: str) -> dict:
+    """The gathers in the while bodies of compiled ``hlo``: name ->
+    number of elements it yields."""
+    out = {}
+    for line in _in_while_bodies(_computations(hlo)):
+        m = _INSTR.match(line)
+        if m and m.group(4) == "gather":
+            out[m.group(1)] = _elems(m.group(2))
+    return out
+
+
+def test_ring_run_loop_gathers_no_table_over_every_host(one_chip,
+                                                        no_persistent_cache):
+    """``ring64.run``'s widths: 64 ranks on 1024 hosts, 126 flows each.
+    Only the 64 rows of the hosts that send are arbitrated, so no gather
+    builds the ``[N, FMAX]`` table of 1024 x 126 flags."""
+    sim = engine.build(
+        state.SimConfig(link=LinkConfig(rate_gbps=800.0, mtu_bytes=4096),
+                        tree=scenarios.TREE_1024_3T),
+        collectives.ring_allreduce(scenarios.TREE_1024_3T, 409600, nodes=64,
+                                   spread=True))
+    d = sim.dims
+    assert (d.N, d.NF, d.FMAX, d.NS) == (1024, 8064, 126, 64)
+    hlo = engine._run_until_done.lower(
+        sim.step_fn, sim.horizon_fn if d.leap else None,
+        _structs(sim.consts, one_chip),
+        _structs(jax.eval_shape(sim.init), one_chip), 80_000, d.superstep,
+        False).compile().as_text()
+    found = gathers(hlo)
+    assert found, "no gather found in the while bodies"
+    assert [g for g, n in found.items() if n == d.N * d.FMAX] == []
