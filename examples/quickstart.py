@@ -48,8 +48,8 @@ def main():
     print(f"\n(ideal uncongested flow: {pkts} packets + 1 RTT; slowdown "
           f"is FCT p99 vs that bound)")
     print("SMaRTT's QuickAdapt collapses the initial burst within one "
-          "target-RTT;\nsee benchmarks/ for the full paper-figure suite "
-          "and api.study for {point x seed} grids.")
+          "target-RTT;\nsee examples/paper_figures.py for the paper-figure "
+          "suite and api.study for {point x seed} grids.")
 
 
 if __name__ == "__main__":

@@ -4,64 +4,25 @@
 backends (trace-only — no XLA compile), lints the source contracts, and
 prints every finding.  Allowlisted findings (``rules.ALLOWLIST``) are
 reported with their justification but do not fail the run; any
-unallowlisted finding exits nonzero, which is the CI gate.
-
-With ``--json-path`` the op-count/bytes rows and per-rule summaries land
-in the ``analysis`` section of the benchmark ledger (via
-``benchmarks.common.write_bench_json``), where CI compares them against
-the committed ledger::
-
-  python -m repro.analysis --json-path analysis_fresh.json
-  python -m benchmarks.check_regression --fresh analysis_fresh.json \\
-      --ledger BENCH_netsim.json --section analysis \\
-      --metric scatter_ops --direction down --threshold 0.0 \\
-      --require perm_512n_3t/jnp
+unallowlisted finding exits nonzero, which is the CI gate.  The
+paper-scale tick's scatter and gather counts are gated separately, by
+``tests/test_gates.py``.
 
 Usage:
   PYTHONPATH=src python -m repro.analysis [--audit-only | --lint-only]
       [--scenarios a,b,...] [--backends jnp,pallas] [--quick]
-      [--json-path PATH] [--list-rules]
+      [--list-rules] [--show-allowlisted]
 """
 
 from __future__ import annotations
 
 import argparse
-import json
 import sys
 import time
 
 from repro.analysis import audit, lint, rules
 
 QUICK_SCENARIOS = ("tiny_3t", "tiny_perm4", "tiny_incast3")
-
-
-def _rule_rows(findings) -> list:
-    """Per-rule ledger summary rows (``findings`` is the gated metric)."""
-    by_rule: dict = {r: [0, 0] for r in rules.RULES}
-    for f in findings:
-        row = by_rule.setdefault(f.rule, [0, 0])
-        row[0] += 1
-        if f.allowlisted:
-            row[1] += 1
-    return [dict(name=f"rule/{rid}", rule=rid, findings=n,
-                 allowlisted=n_allowed, unallowlisted=n - n_allowed,
-                 description=rules.RULES.get(rid, ""))
-            for rid, (n, n_allowed) in sorted(by_rule.items())]
-
-
-def _write_ledger(rows, path, meta) -> str:
-    """The ``analysis`` section, through the shared ledger writer when
-    the benchmarks package is importable (repo-root cwd), else a plain
-    single-section document at ``path``."""
-    try:
-        from benchmarks.common import write_bench_json
-    except ImportError:
-        doc = {"schema": 1,
-               "sections": {"analysis": {"meta": meta, "rows": rows}}}
-        with open(path, "w") as f:
-            json.dump(doc, f, indent=1, sort_keys=True)
-        return path
-    return write_bench_json("analysis", rows, path=path, meta=meta)
 
 
 def main(argv=None) -> int:
@@ -77,9 +38,6 @@ def main(argv=None) -> int:
                    help=f"audit only {', '.join(QUICK_SCENARIOS)}")
     p.add_argument("--audit-only", action="store_true")
     p.add_argument("--lint-only", action="store_true")
-    p.add_argument("--json-path", default=None, metavar="PATH",
-                   help="write the 'analysis' ledger section here "
-                        "(BENCH_netsim.json to update the committed one)")
     p.add_argument("--list-rules", action="store_true")
     p.add_argument("--show-allowlisted", action="store_true",
                    help="print allowlisted findings too (always counted)")
@@ -91,7 +49,7 @@ def main(argv=None) -> int:
         return 0
 
     t0 = time.time()
-    findings, rows = [], []
+    findings = []
 
     if not args.audit_only:
         findings.extend(lint.lint_repo())
@@ -100,11 +58,10 @@ def main(argv=None) -> int:
         names = (args.scenarios.split(",") if args.scenarios
                  else QUICK_SCENARIOS if args.quick else None)
         backends = tuple(b for b in args.backends.split(",") if b)
-        f, r = audit.audit_catalogue(
+        f, _ = audit.audit_catalogue(
             names=names, backends=backends,
             progress=lambda n: print(f"# auditing {n}", flush=True))
         findings.extend(f)
-        rows.extend(r)
 
     bad = [f for f in findings if not f.allowlisted]
     allowed = [f for f in findings if f.allowlisted]
@@ -113,17 +70,6 @@ def main(argv=None) -> int:
     for f in allowed:
         if args.show_allowlisted:
             print(f"ok   {f}")
-
-    if args.json_path:
-        import jax
-        meta = dict(jax=jax.__version__,
-                    findings=len(findings), allowlisted=len(allowed),
-                    unallowlisted=len(bad),
-                    wall_s=round(time.time() - t0, 1))
-        path = _write_ledger(rows + _rule_rows(findings),
-                             args.json_path, meta)
-        print(f"# {len(rows)} op-count rows + "
-              f"{len(rules.RULES)} rule rows -> {path}")
 
     print(f"# {len(findings)} finding(s): {len(bad)} unallowlisted, "
           f"{len(allowed)} allowlisted intentional "

@@ -97,7 +97,7 @@ class OpStats:
 
 
 def op_stats(closed) -> OpStats:
-    """Count the op families the budgets and the ledger track."""
+    """Count the op families the budgets and the op-count gates track."""
     st = OpStats()
     for eqn in walk_eqns(closed):
         name = eqn.primitive.name
@@ -380,8 +380,8 @@ def audit_scenario(sc, backends=("jnp", "pallas"), per_phase: bool = True):
 
     Returns ``(findings, rows)``: findings from JX001/002/003/005 over
     init, the six phases, the step, and the horizon; plus JX004 on a
-    real init state.  ``rows`` are analysis-ledger rows (op counts and
-    bytes per program).
+    real init state.  ``rows`` are op-count rows (op counts and bytes
+    per program; ``tests/test_gates.py`` gates some of them).
     """
     from repro.netsim import engine, state
 
@@ -417,9 +417,9 @@ def audit_scenario(sc, backends=("jnp", "pallas"), per_phase: bool = True):
     return findings, rows
 
 
-# per-phase ledger rows are recorded for these scenarios (the tiered
+# per-phase op-count rows are recorded for these scenarios (the tiered
 # paper-scale set); everything else contributes step-level rows only,
-# keeping the analysis section a few hundred rows, not thousands
+# keeping the rows a few hundred, not thousands
 PER_PHASE_SCENARIOS = ("tiny_3t", "perm_512n_3t", "perm_1024n_3t")
 
 

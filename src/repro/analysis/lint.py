@@ -6,8 +6,6 @@ families (rule docs in ``analysis/rules.py``):
 
   JX101  ``kernels/*/ref.py`` vs ``kernel.py`` signature parity — the
          ``ops.py`` dispatchers assume the pair is call-compatible.
-  JX102  ledger rows in ``BENCH_netsim.json`` must reference registered
-         scenario names (the registry doubles as the ledger key space).
   JX103  no unseeded legacy ``np.random.*`` calls in simulator code.
   JX104  no Python truthiness on traced values in the tick phase
          modules.
@@ -22,7 +20,6 @@ Suppress a line-anchored finding with ``# noqa: JX1xx`` (or a bare
 from __future__ import annotations
 
 import ast
-import json
 import re
 from pathlib import Path
 
@@ -155,48 +152,6 @@ def check_kernel_parity(kernels_dir: Path | None = None) -> list:
                     "JX101", site, f"{rfn.name}|{kfn.name}",
                     f"signature drift: {rfn.name}{rp} vs "
                     f"{kfn.name}{kp} (ops.py dispatches blind)"))
-    return out
-
-
-# --------------------------------------------------------------------------
-# JX102 — ledger keys reference registered scenarios
-# --------------------------------------------------------------------------
-
-# sections whose row names are `scenario/...` when no explicit
-# ``scenario`` field is present; other sections are skipped
-_NAME_PREFIX_SECTIONS = ("perf", "studies", "studies_quick", "failover",
-                         "study_throughput", "collectives")
-
-
-def check_ledger_keys(bench_json: Path | None = None) -> list:
-    """JX102: every ledger row's scenario must be in the registry."""
-    from repro.netsim import scenarios
-
-    if bench_json is None:
-        bench_json = REPO_ROOT / "BENCH_netsim.json"
-    if not bench_json.exists():
-        return []
-    registered = set(scenarios.names())
-    # aliases resolve; also accept the canonical names they map to
-    out, seen = [], set()
-    data = json.loads(bench_json.read_text())
-    for section, body in data.get("sections", {}).items():
-        for row in body.get("rows", []):
-            cand = row.get("scenario")
-            if cand is None:
-                if section not in _NAME_PREFIX_SECTIONS:
-                    continue
-                cand = str(row.get("name", "")).split("/", 1)[0]
-            # strip variant ("+recovery") and algo ("scenario/algo")
-            # decorations some sections fold into the scenario key
-            cand = cand.split("+", 1)[0].split("/", 1)[0]
-            if not cand or cand in registered or cand in seen:
-                continue
-            seen.add(cand)
-            out.append(finding(
-                "JX102", f"BENCH_netsim.json:{section}", cand,
-                f"ledger section {section!r} references scenario "
-                f"{cand!r}, which is not in the scenario registry"))
     return out
 
 
@@ -339,7 +294,6 @@ def lint_repo(root: Path | None = None) -> list:
     root = Path(root) if root else REPO_ROOT
     out: list[Finding] = []
     out.extend(check_kernel_parity(root / "src" / "repro" / "kernels"))
-    out.extend(check_ledger_keys(root / "BENCH_netsim.json"))
     for scope in RANDOM_SCOPE:
         for path in sorted((root / scope).rglob("*.py")):
             out.extend(check_random(path))

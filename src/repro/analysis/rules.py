@@ -35,8 +35,6 @@ source files (stdlib ``ast``; suppress a line with ``# noqa: JX1xx``):
   JX101  kernel trio parity: ``kernels/*/ref.py`` and ``kernel.py``
          public entry points must agree on positional parameter names
          and order (``ops.py`` dispatches between them blind).
-  JX102  ledger key drift: a ``BENCH_netsim.json`` row references a
-         scenario name that is not in the scenario registry.
   JX103  unseeded randomness: legacy ``np.random.*`` module calls in
          simulator code (only seeded ``np.random.default_rng`` is
          reproducible across processes).
@@ -101,7 +99,6 @@ RULES = {
     "JX005": "per-phase scatter/gather op count over budget",
     "JX006": "SimConfig sweepability classification drift",
     "JX101": "kernel ref/kernel signature parity",
-    "JX102": "ledger row references an unregistered scenario",
     "JX103": "unseeded legacy np.random call",
     "JX104": "Python truthiness on traced state in a phase module",
     "JX105": "jax.numpy use in a host-side Consts-building path",

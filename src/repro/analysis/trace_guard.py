@@ -1,7 +1,7 @@
 """Named trace counters + the ``trace_guard`` context manager.
 
 The engine's one-compile contracts ("a whole parameter grid costs exactly
-one step trace", "``run_batch`` builds one init state and broadcasts it")
+one step trace", "a Study builds its lane batch from one init trace")
 used to be enforced through ad-hoc module-level mutable lists
 (``engine.STEP_TRACE_COUNT``, ``state.INIT_TRACE_COUNT``) that every test
 snapshotted by hand.  This module replaces them with one mechanism:

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache, placed from outside the program.
 
-The entry points that drive the simulator (``chip_smoke.py`` and the
-benchmark mains) call :func:`use_compile_cache` first; importing this
-module, or any other, changes nothing, and tests never call it.
+The entry point that drives the simulator on the chip (``chip_smoke.py``)
+calls :func:`use_compile_cache` first; importing this module, or any
+other, changes nothing, and tests never call it.
 """
 
 from __future__ import annotations

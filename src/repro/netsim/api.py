@@ -10,10 +10,9 @@ calls::
                 points=[{"start_cwnd_mult": a} for a in (0.5, 1.0, 1.25)],
                 seeds=range(4)).run()
 
-``study`` fuses the engine's two batching mechanisms — the per-seed salt
-scatter of ``Sim.run_batch`` and the per-point traced-``Consts`` batching
-of the config sweep — into a single ``[P*S]`` vmap lane batch driven by
-one superstep run loop:
+``study`` is the one lane runner: it fuses per-seed salts and per-point
+traced ``Consts`` into a single ``[P*S]`` vmap lane batch driven by one
+superstep run loop:
 
 * **one compile** — the composed step is traced exactly once for the
   whole grid (``trace_guard("engine.step")``, asserted in tests/test_api.py);
@@ -30,7 +29,7 @@ one superstep run loop:
 
 Results come back typed: :class:`RunResult` (per-lane summary, Jain
 fairness, FCT slowdowns) and :class:`StudyResult` (point-major lane grid,
-tidy-row export for the fig scripts and the benchmark ledger).
+tidy-row export).
 
 Fleet-scale execution (DESIGN.md Sec. 7) layers three orthogonal knobs
 onto ``Study.run`` without touching the fast path:
@@ -44,8 +43,7 @@ onto ``Study.run`` without touching the fast path:
   finished chunk to the cache — a killed grid resumes from the last
   completed chunk, bit-equal to an uninterrupted run.
 
-``engine.build(cfg, wl).run(...)`` and ``sweep.build_sweep(...)`` remain
-as thin compatibility wrappers over the same machinery.
+A single unbatched run is ``run(...)``, over ``engine.build(cfg, wl).run``.
 """
 
 from __future__ import annotations
@@ -126,7 +124,7 @@ def _norm_point(point) -> tuple:
 
 
 def point_tag(point) -> str:
-    """Human/ledger tag for a sweep point (``"base"`` for the empty one)."""
+    """Human-readable tag for a sweep point (``"base"`` for the empty one)."""
     kv = _norm_point(point)
     return "+".join(f"{k}={v:g}" for k, v in kv) if kv else "base"
 
@@ -387,7 +385,7 @@ class RunResult:
     @property
     def cct(self) -> int:
         """Slowest collective's CCT (-1: none defined, or any collective
-        unfinished) — the scalar the bench ledger tracks."""
+        unfinished) — the scalar ``tests/test_gates.py`` gates."""
         ccts = self.cct_by_coll
         if not ccts or any(v < 0 for v in ccts.values()):
             return -1
@@ -486,7 +484,7 @@ class RunResult:
                 f"[{self.point_tag}]/s{self.seed}")
 
     def row(self) -> dict:
-        """One tidy, JSON-able row for fig scripts and the bench ledger."""
+        """One tidy, JSON-able row (also what the result cache stores)."""
         d = dict(
             name=self.name, scenario=self.scenario, algo=self.algo,
             lb=self.lb, point=dict(self.point), seed=self.seed,
@@ -505,11 +503,11 @@ class RunResult:
         )
         if self.coll_id is not None and np.any(self.coll_id >= 0):
             # collective metrics, only when the workload groups flows
-            # (keeps plain flow-list ledger rows unchanged)
+            # (keeps plain flow-list rows unchanged)
             d.update(cct=self.cct, n_collectives=len(self.cct_by_coll))
         if self.first_fault >= 0:
             # recovery metrics, only for runs with an active fault
-            # schedule (keeps fault-free ledger rows unchanged)
+            # schedule (keeps fault-free rows unchanged)
             d.update(
                 fault_ticks=self.fault_ticks,
                 delivered_fault_frac=round(self.delivered_fault_frac, 6),
@@ -588,7 +586,7 @@ class StudyResult:
         return self.results[point_idx * s:(point_idx + 1) * s]
 
     def rows(self) -> list:
-        """Tidy rows (one per lane) for fig scripts / the bench ledger."""
+        """Tidy rows, one per lane."""
         return [r.row() for r in self.results]
 
     def best(self, metric: str = "completion") -> RunResult:

@@ -39,11 +39,11 @@ enqueue-rank/arbitration pair (``fabric_backend`` ->
 (``transport_backend`` -> ``kernels/ring_drain``); every backend pair is
 bit-for-bit interchangeable (DESIGN.md Sec. 6.4).  The phases compose
 over a ``Consts`` bundle of traced numerics — so retuning any parameter,
-or sweeping a whole grid of them, reuses one compiled step.  Batched execution (seed batches, sweep
-grids, full seed x point studies) lives in the experiment API
-(``netsim/api.py``, DESIGN.md Sec. 7): its lane loop vmaps ``step_fn``
-over ``[P*S]`` lanes with per-lane exit gating and leap horizons;
-``Sim.run_batch`` here is a thin wrapper over it.
+or sweeping a whole grid of them, reuses one compiled step.  Batched
+execution (seed batches, sweep grids, full seed x point studies) lives in
+the experiment API (``netsim/api.py``, DESIGN.md Sec. 7): its lane loop
+(``shard.run_lanes``) vmaps ``step_fn`` over ``[P*S]`` lanes with per-lane
+exit gating and leap horizons.
 """
 
 from __future__ import annotations
@@ -73,7 +73,7 @@ I32 = jnp.int32
 F32 = jnp.float32
 
 # Incremented each time a composed step function is *traced* (not executed).
-# ``tests/test_sweep.py`` asserts a whole parameter grid costs exactly one:
+# ``tests/test_api.py`` asserts a whole parameter grid costs exactly one:
 # ``with trace_guard("engine.step", expect=1): ...`` (repro.analysis).
 _STEP_TRACES = _trace_counter("engine.step")
 
@@ -148,34 +148,6 @@ class Sim:
 
     def run_trace(self, ticks: int, trace_flows: int = 8):
         return _run_trace(self.step, self.init(), ticks, trace_flows)
-
-    def run_batch(self, seeds, max_ticks: int, mesh=None) -> SimState:
-        """vmap a batch of decorrelated runs (per-seed RED/ECMP salts) —
-        a thin compatibility wrapper over the sharded lane loop
-        (``shard.run_lanes``; one compiled step, per-lane exit gating and
-        leap horizons, so each lane matches its standalone ``run(seed=s)``
-        bit-for-bit).  ``mesh`` (a ``shard.lane_mesh()``) spreads the
-        batch across devices; the default stays single-device vmap.
-
-        The init state is built once and broadcast over the batch —
-        only the per-seed ``salt`` is scattered (asserted by the
-        ``trace_guard("state.init")`` check in tests/test_engine_leap.py);
-        each broadcast leaf is a fresh buffer, so donation stays legal.
-        """
-        import numpy as _np
-
-        from repro.netsim import api, shard
-        seeds = jnp.asarray(_np.asarray(seeds), I32)
-        base = self.init()
-        states = jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (seeds.shape[0],) + x.shape),
-            base)
-        states = states._replace(salt=seeds)
-        return shard.run_lanes(self.step_fn,
-                               self.horizon_fn if self.dims.leap else None,
-                               api.no_axes(self.consts), max_ticks,
-                               self.dims.superstep, self.consts, states,
-                               mesh=mesh)
 
 
 # --------------------------------------------------------------------------

@@ -9,9 +9,10 @@ tick budget.  ``netsim/api.py`` takes a Scenario and lowers
 ``Scenario x sweep points x seeds`` onto one compiled step.
 
 The registry maps short stable names (``"incast8_32n"``, ``"perm64"``,
-``"sparse_heavy_32n"``, ...) to factories; the names double as benchmark
-ledger keys (``BENCH_netsim.json``), so keep them stable.  ``scenario()``
-resolves a name and applies per-call config overrides::
+``"sparse_heavy_32n"``, ...) to factories; the recorded digests
+(``tests/data/scenario_digests.json``) and the gates on simulated results
+(``tests/test_gates.py``) are keyed by these names, so keep them stable.
+``scenario()`` resolves a name and applies per-call config overrides::
 
     sc = scenario("perm64", algo="swift")          # same grid, new CC
     sc = scenario("incast8_32n", max_ticks=30_000)
@@ -31,9 +32,9 @@ KiB = 1024
 MiB = 1024 * 1024
 
 # Standard scaled topologies (EXPERIMENTS.md Sec. "Scaled topologies").
-# benchmarks/common.py re-exports these; the paper's 1024-node 800 Gb/s
-# fabric is scaled to CPU-tractable sizes with relative behavior as the
-# reproduction target.
+# The paper's 1024-node 800 Gb/s fabric is scaled to CPU-tractable sizes
+# with relative behavior as the reproduction target
+# (examples/paper_figures.py).
 TREE_8TO1 = FatTreeConfig(racks=8, nodes_per_rack=16, uplinks=2)   # 128 nodes
 TREE_4TO1 = FatTreeConfig(racks=4, nodes_per_rack=16, uplinks=4)   # 64 nodes
 TREE_2TO1 = FatTreeConfig(racks=4, nodes_per_rack=16, uplinks=8)   # 64 nodes
@@ -132,10 +133,10 @@ def _std(name: str, tree: FatTreeConfig, wl: Workload,
 
 
 # --------------------------------------------------------------------------
-# catalogue — names are ledger keys (BENCH_netsim.json); keep stable
+# catalogue — names are digest and gate keys (tests/); keep stable
 # --------------------------------------------------------------------------
 
-# tiny smoke scenarios (CI bench smoke, `--quick` modes)
+# tiny smoke scenarios (tests, the quickstart's three-tier smoke)
 register("tiny_incast3", lambda: _std(
     "tiny_incast3", TREE_TINY,
     workloads.incast(TREE_TINY, degree=3, size_bytes=16 * KiB, seed=0),
@@ -150,7 +151,7 @@ register("tiny_sparse", lambda: _std(
                            size_cap=256 * KiB, gap_mean=1500.0, seed=1),
     30_000))
 
-# dense standard scenarios (perf ledger rows, figures)
+# dense standard scenarios
 register("incast8_32n", lambda: _std(
     "incast8_32n", TREE_FLAT,
     workloads.incast(TREE_FLAT, degree=8, size_bytes=512 * KiB, seed=0),
@@ -172,7 +173,7 @@ register("alltoall16_w4", lambda: _std(
     workloads.alltoall(TREE_4TO1, size_bytes=64 * KiB, window=4, nodes=16),
     200_000))
 
-# small 4:1 grid for tuning studies (benchmarks/sweep.py)
+# small 4:1 grid for tuning studies (tests/test_gates.py's Study gate)
 register("incast8_16n", lambda: _std(
     "incast8_16n", TREE_16,
     workloads.incast(TREE_16, degree=8, size_bytes=64 * 4096, seed=3),
@@ -216,9 +217,9 @@ register("perm_128n_3t", lambda: _std(
     workloads.permutation(TREE_128_3T, size_bytes=256 * KiB, seed=7),
     120_000))
 
-# failover scenarios (ISSUE 8): dynamic FaultSchedule timelines on the
-# 128-node three-tier tree, benched with and without the failure-recovery
-# transport knobs (benchmarks/failover.py).  1 MiB flows so the kill lands
+# failover scenarios: dynamic FaultSchedule timelines on the
+# 128-node three-tier tree, run with and without the failure-recovery
+# transport knobs (tests/test_gates.py).  1 MiB flows so the kill lands
 # mid-flight, *after* the REPS explore phase — the stranding mode the
 # recovery knobs exist for is a flow retransmitting past-explore packets
 # onto a dead cached entropy forever.
@@ -250,8 +251,8 @@ register("switchkill_128n_3t", lambda: _std(
         faults.FaultEvent(t=3_000, kind="switch", i=17, period=1)))))
 
 # dependency-driven collectives (DESIGN.md Sec. 11): the chunk DAG gates
-# each flow on its parents' delivered bytes; rows land in the BENCH
-# `collectives` section with CCT next to FCT (benchmarks/collectives.py).
+# each flow on its parents' delivered bytes; ``RunResult.cct`` reports the
+# collective completion time (gated in tests/test_gates.py).
 register("tiny_allreduce_ring", lambda: _std(
     "tiny_allreduce_ring", TREE_3T_TINY,
     collectives.ring_allreduce(TREE_3T_TINY, chunk_bytes=8 * KiB, nodes=8),

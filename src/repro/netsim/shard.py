@@ -144,8 +144,7 @@ def lane_mesh(devices=None) -> Mesh:
     """A 1-D device mesh over ``devices`` (default: every visible
     device) with the single axis ``"lanes"``.  On CPU, multiple host
     devices come from ``XLA_FLAGS=--xla_force_host_platform_device_count=D``
-    (set before jax initializes — CI's multi-device job and
-    ``benchmarks/study_throughput.py`` use exactly that)."""
+    (set before jax initializes — CI's multi-device job does)."""
     devs = list(devices) if devices is not None else list(jax.devices())
     return Mesh(np.asarray(devs), (LANE_AXIS,))
 
@@ -227,7 +226,7 @@ def run_lanes(step_fn, horizon_fn, axes, max_ticks: int, superstep: int,
               consts_b, states: state.SimState, mesh: Mesh | None = None,
               counters: bool = False, live_flows=None):
     """Run a ``[B]`` lane batch to completion — THE batched run loop
-    behind ``Study``/``Sim.run_batch``/``Sweep.run``.
+    behind ``Study``.
 
     ``mesh=None`` (or a 1-device mesh) is the single-device vmap path,
     unchanged from PR 4.  A larger mesh pads the batch to a

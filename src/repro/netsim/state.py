@@ -7,7 +7,7 @@ This module owns every container the phase pipeline operates on:
                  safe to close over in jitted code; changing any retraces)
   ``Consts``     *traced* numeric constants (a jax pytree — changing any
                  value, e.g. a CC parameter or the RED thresholds, reuses
-                 the compiled step; ``netsim/sweep.py`` vmaps over a batch
+                 the compiled step; ``api.study`` vmaps over a batch
                  of these for one-compile parameter sweeps)
   ``SimState``   the per-tick mutable world
 
@@ -570,8 +570,8 @@ def derive(cfg: SimConfig, wl: Workload):
 
 
 # Counted each time ``init_state`` runs (eagerly or as a trace).
-# ``tests/test_engine_leap.py`` asserts ``Sim.run_batch`` builds exactly one
-# init state and broadcasts it, rather than re-deriving it per seed:
+# ``tests/test_api.py`` asserts a Study builds its whole lane batch from one
+# vmapped init trace, rather than one per lane:
 # ``with trace_guard("state.init", expect=1): ...`` (repro.analysis).
 _INIT_TRACES = _trace_counter("state.init")
 

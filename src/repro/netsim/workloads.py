@@ -295,8 +295,8 @@ def heavy_tailed(tree: FatTreeConfig, n_flows: int, *,
     with a heavy tail of multi-BDP ones, separated by idle stretches of
     many base RTTs.  The time-stepped engine burns a tick per MTU-time
     across those stretches; the leap-enabled engine skips them in closed
-    form, which is exactly what `benchmarks/perf.py` measures on this
-    pattern (UEC-style sparse/large-message regimes, arXiv 2508.08906).
+    form on this pattern (UEC-style sparse/large-message regimes,
+    arXiv 2508.08906).
     """
     n = tree.n_nodes
     rng = np.random.default_rng(seed)

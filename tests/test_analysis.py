@@ -150,16 +150,6 @@ def test_jx101_kwonly_statics_are_parity(tmp_path):
     assert lint.check_kernel_parity(tmp_path) == []
 
 
-def test_jx102_unregistered_scenario_trips(tmp_path):
-    bench = tmp_path / "BENCH_netsim.json"
-    bench.write_text(
-        '{"schema": 1, "sections": {"perf": {"rows": '
-        '[{"name": "no_such_scenario/jnp/k40", "ticks_per_sec": 1}]}}}')
-    found = lint.check_ledger_keys(bench)
-    assert _rules_of(found) == {"JX102"}
-    assert found[0].token == "no_such_scenario"
-
-
 def test_jx103_unseeded_random_trips(tmp_path):
     bad = tmp_path / "bad.py"
     bad.write_text(textwrap.dedent("""\

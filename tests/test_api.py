@@ -1,8 +1,7 @@
 """Experiment API (DESIGN.md Sec. 7): the declarative Scenario/Study
 entry point must lower a {point x seed} grid onto ONE compiled step while
-keeping every lane bit-for-bit equal to its standalone execution — and
-the legacy entry points (``engine.build(...).run``, ``build_sweep``) must
-stay exact wrappers over the same machinery."""
+keeping every lane bit-for-bit equal to its standalone execution, in
+``points`` order; only numeric keys are sweepable (``apply_point``)."""
 
 import dataclasses
 
@@ -15,16 +14,26 @@ from repro.netsim import api, engine, scenarios, workloads
 from repro.netsim.api import apply_point
 from repro.netsim.scenarios import Scenario, scenario
 from repro.netsim.state import SimConfig
-from repro.netsim.sweep import build_sweep
 from repro.netsim.units import FatTreeConfig, LinkConfig
 
 TREE = FatTreeConfig(racks=2, nodes_per_rack=4, uplinks=2)
 LINK = LinkConfig()
+CFG = SimConfig(link=LINK, tree=TREE, algo="smartt", lb="reps")
 
 POINTS = ({}, {"start_cwnd_mult": 0.5}, {"rto_mult": 5.0},
           {"start_cwnd_mult": 0.75, "react_every": 4})
 SEEDS = (0, 1, 2, 3)
 MAX_TICKS = 30_000
+
+# a 9-point tuning grid: initial window x reaction granularity, plus a CC
+# gain and RED thresholds swept together
+GRID9 = (
+    [{"start_cwnd_mult": a, "react_every": r}
+     for a in (0.5, 0.75, 1.0, 1.25) for r in (1, 4)]
+    + [{"fd": 0.4, "kmin_frac": 0.1, "kmax_frac": 0.5}]
+)
+# points out of sorted order: lane i must still be points[i]
+SHUFFLED = [{"start_cwnd_mult": a} for a in (1.25, 0.5, 1.0)]
 
 
 def _scenario(leap=True, **cfg_kw) -> Scenario:
@@ -50,27 +59,36 @@ def _lane(states, i):
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("leap", [True, False])
-def test_study_one_compile_and_lanes_match_standalone(leap):
-    """A >=4-point x >=4-seed Study compiles exactly one step, and every
-    lane's final state equals the standalone ``Sim.run`` of that
-    (point, seed) across the FULL SimState pytree — ``now``, metrics
-    counters, and RTT histograms included."""
+@pytest.mark.parametrize("leap,points,seeds", [
+    (True, POINTS, SEEDS),
+    (False, POINTS, SEEDS),
+    (True, GRID9, (0,)),
+    (False, GRID9, (0,)),
+    (True, SHUFFLED, (0,)),
+], ids=["True", "False", "grid9", "grid9-noleap", "shuffled"])
+def test_study_one_compile_and_lanes_match_standalone(leap, points, seeds):
+    """A whole {point x seed} Study compiles exactly one step, every lane
+    finishes, and every lane's final state equals the standalone
+    ``Sim.run`` of that (point, seed) across the FULL SimState pytree —
+    ``now``, metrics counters, and RTT histograms included — in
+    ``points`` order.  The swept knobs actually change the outcome."""
     sc = _scenario(leap=leap)
-    st_obj = api.study(sc, points=POINTS, seeds=SEEDS)
-    assert st_obj.n_lanes == len(POINTS) * len(SEEDS)
+    st_obj = api.study(sc, points=points, seeds=seeds)
+    assert st_obj.n_lanes == len(points) * len(seeds)
 
     with trace_guard("engine.step", expect=1):
         res = st_obj.run()
+    assert all(r.all_done for r in res)
+    assert len({r.completion for r in res}) > 1
 
-    for pi, pt in enumerate(POINTS):
+    for pi, pt in enumerate(points):
         cfg_i = apply_point(sc.cfg, pt)
         sim_i = engine.build(cfg_i, sc.wl)
         assert sim_i.dims.leap == leap
-        for si, seed in enumerate(SEEDS):
+        for si, seed in enumerate(seeds):
             st_i = sim_i.run(max_ticks=MAX_TICKS, seed=seed)
             _assert_state_equal(st_i,
-                                _lane(res.states, pi * len(SEEDS) + si))
+                                _lane(res.states, pi * len(seeds) + si))
             # the typed lane result reflects the same run
             r = res.lane(pi, si)
             assert r.seed == seed and dict(r.point) == pt
@@ -115,32 +133,6 @@ def test_sim_run_passes_consts_as_arguments(monkeypatch):
     assert len(lowered) == 1 and bdp not in lowered[0]
 
 
-def test_build_sweep_lanes_match_study():
-    """Compatibility wrapper: ``build_sweep`` runs the same lane loop, so
-    its [P] states are bit-identical to the seed-0 lanes of a Study over
-    the same points (and therefore to standalone builds)."""
-    sc = _scenario()
-    states_sweep = build_sweep(sc.cfg, sc.wl, list(POINTS)).run(
-        max_ticks=MAX_TICKS)
-    res = api.study(sc, points=POINTS, seeds=(0, 1)).run()
-    for pi in range(len(POINTS)):
-        _assert_state_equal(_lane(states_sweep, pi),
-                            _lane(res.states, pi * 2))
-
-
-def test_run_batch_matches_study_seed_lanes():
-    """Compatibility wrapper: ``Sim.run_batch`` is the seeds-only Study —
-    bit-identical states, including per-lane ``now``."""
-    sc = _scenario()
-    sim = engine.build(sc.cfg, sc.wl)
-    stb = sim.run_batch(np.asarray(SEEDS), max_ticks=MAX_TICKS)
-    res = api.study(sc, seeds=SEEDS).run()
-    _assert_state_equal(stb, res.states)
-    for si, seed in enumerate(SEEDS):
-        st_i = sim.run(max_ticks=MAX_TICKS, seed=seed)
-        _assert_state_equal(st_i, _lane(stb, si))
-
-
 def test_study_single_init_trace():
     """The [P*S] lane batch comes from ONE vmapped init_state trace."""
     st_obj = api.study(_scenario(), points=POINTS, seeds=SEEDS)
@@ -155,18 +147,38 @@ def test_study_single_init_trace():
 # --------------------------------------------------------------------------
 
 
-def test_study_rejects_dims_changing_and_unknown_keys():
-    sc = _scenario()
+@pytest.mark.parametrize("kw,exc,match", [
+    (dict(points=[{"superstep": 4}]), KeyError, "changes Dims"),
+    (dict(points=[{"trimming": 0.0}]), KeyError, "changes Dims"),
+    (dict(points=[{"algo": 1.0}]), KeyError, "changes Dims"),
+    (dict(points=[{"quantum_entanglement": 1.0}]), KeyError, "unsweepable"),
+    (dict(points=[]), ValueError, "empty sweep"),
+    (dict(seeds=[]), ValueError, "empty seeds"),
+], ids=["superstep", "trimming", "algo", "unknown", "no_points", "no_seeds"])
+def test_study_rejects_dims_changing_and_unknown_keys(kw, exc, match):
+    with pytest.raises(exc, match=match):
+        api.study(_scenario(), **kw)
+
+
+def test_apply_point_routes_cc_keys_into_overrides():
+    cfg = apply_point(CFG, {"fd": 0.5, "start_cwnd_mult": 0.7})
+    assert ("fd", 0.5) in cfg.cc_overrides
+    assert cfg.start_cwnd_mult == 0.7
+
+
+def test_apply_point_unknown_key_names_the_valid_ones():
+    with pytest.raises(KeyError, match="unsweepable key 'bogus'") as ei:
+        apply_point(CFG, {"bogus": 1.0})
+    assert "start_cwnd_mult" in str(ei.value)      # actionable: lists keys
+
+
+@pytest.mark.parametrize("key", ["superstep", "leap", "trimming",
+                                 "cc_backend", "lb", "tree"])
+def test_apply_point_dims_changing_key_raises(key):
+    """Keys that change Dims (shapes/branch selectors) cannot ride one
+    compiled step; the error says to build one Scenario per value."""
     with pytest.raises(KeyError, match="changes Dims"):
-        api.study(sc, points=[{"superstep": 4}])
-    with pytest.raises(KeyError, match="changes Dims"):
-        api.study(sc, points=[{"trimming": 0.0}])
-    with pytest.raises(KeyError, match="unsweepable"):
-        api.study(sc, points=[{"quantum_entanglement": 1.0}])
-    with pytest.raises(ValueError, match="empty sweep"):
-        api.study(sc, points=[])
-    with pytest.raises(ValueError, match="empty seeds"):
-        api.study(sc, seeds=[])
+        apply_point(CFG, {key: 1})
 
 
 def test_study_validates_workload_up_front():

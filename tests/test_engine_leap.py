@@ -4,8 +4,8 @@ must be bit-for-bit identical to leap-off across the *full* state pytree
 ticks that are state no-ops, it never approximates.  Covered regimes:
 dense incast/permutation/alltoall on both CC backends, credit-based
 grants, timeout recovery without trimming, faulted links, the sparse
-heavy-tailed scenario the perf benchmark leans on, and the batched /
-sweep run loops with their min-over-batch leap."""
+heavy-tailed scenario, and the Study lane loop (seed lanes and sweep
+points) with its per-lane leap."""
 
 import dataclasses
 
@@ -13,10 +13,9 @@ import jax
 import numpy as np
 import pytest
 
-from repro.analysis import trace_guard
-from repro.netsim import collectives, workloads
+from repro.netsim import api, collectives, workloads
 from repro.netsim.engine import SimConfig, build
-from repro.netsim.sweep import build_sweep
+from repro.netsim.scenarios import Scenario
 from repro.netsim.units import FatTreeConfig, LinkConfig
 
 TREE = FatTreeConfig(racks=2, nodes_per_rack=4, uplinks=2)
@@ -222,41 +221,33 @@ def test_leap_forced_off_for_paced_and_plb():
     assert build(SimConfig(link=LINK, tree=TREE, leap=True), wl).dims.leap
 
 
-def test_leap_run_batch_per_lane_horizons():
+def _lanes(tree, wl, leap, max_ticks, **study_kw):
+    """Final ``[B]`` states of a Study over ``wl`` (leap on or off)."""
+    sc = Scenario(name=wl.name, wl=wl,
+                  cfg=SimConfig(link=LINK, tree=tree, leap=leap))
+    return api.study(sc, **study_kw).run_states(max_ticks=max_ticks)
+
+
+def test_leap_seed_lanes_per_lane_horizons():
     """Batched lanes leap independently (each by its own horizon, frozen
-    once done — api._run_lanes); every lane must match its leap-off
+    once done — shard.run_lanes); every lane must match its leap-off
     twin bit-for-bit."""
     wl = workloads.heavy_tailed(OVERSUB, 8, size_base=4 * 4096,
                                 size_cap=64 * 4096, gap_mean=900.0, seed=7)
-    sim_on = build(SimConfig(link=LINK, tree=OVERSUB, leap=True), wl)
-    sim_off = build(SimConfig(link=LINK, tree=OVERSUB, leap=False), wl)
-    st_on = sim_on.run_batch(np.arange(4), max_ticks=40000)
-    st_off = sim_off.run_batch(np.arange(4), max_ticks=40000)
+    st_on = _lanes(OVERSUB, wl, True, 40000, seeds=range(4))
+    st_off = _lanes(OVERSUB, wl, False, 40000, seeds=range(4))
     _assert_state_equal(st_off, st_on)
-
-
-def test_run_batch_builds_one_init_and_broadcasts():
-    """Satellite contract: run_batch derives a single init state and
-    broadcasts it over the batch, scattering only the per-seed salt."""
-    wl = workloads.incast(TREE, degree=3, size_bytes=16 * 4096, seed=0)
-    sim = build(SimConfig(link=LINK, tree=TREE), wl)
-    with trace_guard("state.init", expect=1):
-        st = sim.run_batch(np.arange(5), max_ticks=30000)
-        st.now.block_until_ready()
-    np.testing.assert_array_equal(np.asarray(st.salt), np.arange(5))
 
 
 def test_leap_sweep_per_point_horizons():
     """The sweep leap evaluates each grid point's horizon under its own
     swept Consts (different RTOs / start windows!) and each lane jumps by
-    its own distance (api._run_lanes)."""
+    its own distance (shard.run_lanes)."""
     wl = workloads.incast(TREE, degree=4, size_bytes=32 * 4096, seed=1)
     points = [{"start_cwnd_mult": a, "rto_mult": r}
               for a, r in ((0.5, 3.0), (1.25, 5.0))]
-    st_on = build_sweep(SimConfig(link=LINK, tree=TREE, leap=True),
-                        wl, points).run(max_ticks=30000)
-    st_off = build_sweep(SimConfig(link=LINK, tree=TREE, leap=False),
-                         wl, points).run(max_ticks=30000)
+    st_on = _lanes(TREE, wl, True, 30000, points=points)
+    st_off = _lanes(TREE, wl, False, 30000, points=points)
     _assert_state_equal(st_off, st_on)
 
 

@@ -1,16 +1,16 @@
 """Superstep execution engine: K>1 runs must be bit-for-bit identical to
 K=1 (the per-tick gate makes fused ticks exact, not approximate), across
-CC backends, the batched runner, and the config sweep; and the per-seed
-salt decorrelation of run_batch must actually change RED marking."""
+CC backends, the seed lanes, and the config sweep; and the per-seed
+salt decorrelation of Study seed lanes must actually change RED marking."""
 
 import jax
 import numpy as np
 import pytest
 
 from repro.analysis import trace_guard
-from repro.netsim import collectives, workloads
+from repro.netsim import api, collectives, workloads
 from repro.netsim.engine import SimConfig, build
-from repro.netsim.sweep import build_sweep
+from repro.netsim.scenarios import Scenario
 from repro.netsim.units import FatTreeConfig, LinkConfig
 
 TREE = FatTreeConfig(racks=2, nodes_per_rack=4, uplinks=2)
@@ -83,14 +83,15 @@ def test_superstep_exact_on_three_tier_core_congestion():
     _assert_state_equal(st1, stk)
 
 
-def test_run_batch_matches_k1_and_decorrelates_red():
-    """run_batch composes with supersteps, and the per-seed salts change
-    RED marking outcomes (different mark draws -> different trajectories),
-    while seed 0 reproduces the unbatched run exactly."""
+def test_seed_lanes_match_k1_and_decorrelate_red():
+    """Study seed lanes compose with supersteps, and the per-seed salts
+    change RED marking outcomes (different mark draws -> different
+    trajectories), while seed 0 reproduces the unbatched run exactly."""
     wl = workloads.permutation(OVERSUB, size_bytes=64 * 4096, seed=2)
     sim1 = build(SimConfig(link=LINK, tree=OVERSUB, superstep=1), wl)
-    simk = build(SimConfig(link=LINK, tree=OVERSUB, superstep=0), wl)
-    stb = simk.run_batch(np.arange(4), max_ticks=30000)
+    sck = Scenario(name=wl.name, wl=wl,
+                   cfg=SimConfig(link=LINK, tree=OVERSUB, superstep=0))
+    stb = api.study(sck, seeds=range(4)).run_states(max_ticks=30000)
     st1 = sim1.run(max_ticks=30000)
 
     # batch element 0 carries salt 0 == the unbatched run
@@ -114,12 +115,13 @@ def test_sweep_composes_with_supersteps():
     cfg1 = SimConfig(link=LINK, tree=TREE, superstep=1)
     cfgk = SimConfig(link=LINK, tree=TREE, superstep=13)
 
-    swk = build_sweep(cfgk, wl, points)
+    swk = api.study(Scenario(name=wl.name, cfg=cfgk, wl=wl), points=points)
     with trace_guard("engine.step", expect=1):
-        states_k = swk.run(max_ticks=30000)
+        states_k = swk.run_states(max_ticks=30000)
         states_k.now.block_until_ready()
 
-    states_1 = build_sweep(cfg1, wl, points).run(max_ticks=30000)
+    states_1 = api.study(Scenario(name=wl.name, cfg=cfg1, wl=wl),
+                         points=points).run_states(max_ticks=30000)
     np.testing.assert_array_equal(np.asarray(states_1.fct),
                                   np.asarray(states_k.fct))
     np.testing.assert_array_equal(np.asarray(states_1.goodput),
@@ -137,28 +139,6 @@ def test_donated_state_is_consumed():
     sim, st_a = _run(TREE, wl, superstep=0)
     st_b = sim.run(max_ticks=30000)
     np.testing.assert_array_equal(np.asarray(st_a.fct), np.asarray(st_b.fct))
-
-
-def test_legacy_baseline_matches_production_trajectory():
-    """benchmarks/legacy.py (the perf baseline) must stay a faithful
-    *semantic* twin of the production step — only the op structure may
-    differ — so ticks/sec comparisons measure the engine, not the load.
-    (Compared on simulated outcomes, not the full pytree: the baseline
-    intentionally keeps the seed's unconditional trim_seen ledger, which
-    the production step gates on credit-based algorithms.)"""
-    pytest.importorskip("benchmarks.legacy")
-    from benchmarks.legacy import build_legacy
-    from benchmarks.perf import _run_k1_ungated
-
-    wl = workloads.permutation(OVERSUB, size_bytes=64 * 4096, seed=4)
-    cfg = SimConfig(link=LINK, tree=OVERSUB)
-    leg = build_legacy(cfg, wl)
-    st_l = _run_k1_ungated(leg.step, leg.init(), 30000)
-    _, st_p = _run(OVERSUB, wl, superstep=0)
-    np.testing.assert_array_equal(np.asarray(st_l.fct), np.asarray(st_p.fct))
-    np.testing.assert_array_equal(np.asarray(st_l.goodput),
-                                  np.asarray(st_p.goodput))
-    assert int(st_l.now) == int(st_p.now)
 
 
 def test_superstep_exact_dependency_gated_collectives():

@@ -13,8 +13,9 @@ import numpy as np
 import pytest
 
 from repro.kernels.enqueue_arb import ops as enqueue_arb_ops
-from repro.netsim import state, workloads
+from repro.netsim import api, state, workloads
 from repro.netsim.engine import SimConfig, build
+from repro.netsim.scenarios import Scenario
 from repro.netsim.units import FatTreeConfig, LinkConfig
 
 I32 = jnp.int32
@@ -101,11 +102,12 @@ def test_phases_on_loop_form_match_public_indexing(backend):
 
 
 @pytest.mark.parametrize("max_ticks", [60, 30000], ids=["mid_run", "done"])
-def test_run_batch_ring_equals_standalone_runs(max_ticks):
+def test_seed_lanes_ring_equals_standalone_runs(max_ticks):
     wl = workloads.incast(TREE16, degree=12, size_bytes=32 * 4096, seed=1)
     sim = build(SimConfig(link=LINK, tree=TREE16), wl)
     seeds = (3, 5, 7, 11)
-    batch = sim.run_batch(seeds, max_ticks=max_ticks)
+    batch = api.study(Scenario(name=wl.name, cfg=sim.cfg, wl=wl),
+                      seeds=seeds).run_states(max_ticks=max_ticks)
     assert batch.q_fields.shape == (4, sim.dims.NQ + 1, sim.dims.CAP, 5)
     for i, s in enumerate(seeds):
         one = sim.run(max_ticks=max_ticks, seed=s)

@@ -14,7 +14,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.netsim import api, engine, shard
+from repro.netsim import api, shard
 
 MULTI = jax.device_count() >= 2
 
@@ -142,13 +142,11 @@ def test_multi_device_study_run_results_match():
 
 
 @pytest.mark.skipif(not MULTI, reason="needs >= 2 devices")
-def test_multi_device_run_batch_matches():
-    """``Sim.run_batch(mesh=...)`` parity — and transitively parity with
-    every standalone ``run(seed=s)`` (test_api covers that leg)."""
-    sc = api._resolve("tiny_3t")
-    sim = engine.build(sc.cfg, sc.wl)
-    seeds = np.arange(5)
-    ref = sim.run_batch(seeds, max_ticks=sc.max_ticks)
-    out = sim.run_batch(seeds, max_ticks=sc.max_ticks,
-                        mesh=shard.lane_mesh())
+def test_multi_device_seed_lanes_match():
+    """Seed-only ``Study.run_states(mesh=...)`` parity — and transitively
+    parity with every standalone ``run(seed=s)`` (test_api covers that
+    leg)."""
+    st = api.study("tiny_3t", seeds=range(5))
+    ref = st.run_states()
+    out = st.run_states(mesh=shard.lane_mesh())
     _assert_state_equal(ref, out)

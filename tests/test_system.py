@@ -1,11 +1,15 @@
 """End-to-end behaviour tests for the whole system: the paper's headline
 result on the netsim, and the training stack's learn+restart loop."""
 
+import importlib.util
+from pathlib import Path
+
 import numpy as np
 
+from repro.netsim import api, workloads
 from repro.netsim.engine import SimConfig, build, jain_fairness, summarize
+from repro.netsim.scenarios import Scenario
 from repro.netsim.units import FatTreeConfig, LinkConfig
-from repro.netsim import workloads
 
 
 def test_headline_smartt_beats_baselines_on_oversubscribed_permutation():
@@ -34,8 +38,9 @@ def test_batched_runs_are_decorrelated_and_complete():
     link = LinkConfig()
     tree = FatTreeConfig(racks=2, nodes_per_rack=4, uplinks=2)
     wl = workloads.permutation(tree, size_bytes=64 * 4096, seed=2)
-    sim = build(SimConfig(link=link, tree=tree, algo="smartt", lb="reps"), wl)
-    st = sim.run_batch(np.arange(4), max_ticks=30000)
+    sc = Scenario(name=wl.name, wl=wl,
+                  cfg=SimConfig(link=link, tree=tree, algo="smartt", lb="reps"))
+    st = api.study(sc, seeds=range(4)).run_states(max_ticks=30000)
     assert bool(np.all(np.asarray(st.done)))
     fcts = [int(np.asarray(st.fct)[i].max()) for i in range(4)]
     assert len(set(fcts)) > 1          # per-seed salts decorrelate runs
@@ -69,10 +74,12 @@ def test_train_learns_and_restarts(tmp_path):
 
 
 def test_bench_run_exits_nonzero_when_a_figure_fails(monkeypatch, capsys):
-    """benchmarks.run prints every figure's error row, keeps going, and
-    exits non-zero if any figure failed."""
-    from benchmarks import fig_benchmarks, run
-    from repro import compile_cache
+    """examples/paper_figures.py prints every figure's error row, keeps
+    going, and exits non-zero if any figure failed."""
+    path = Path(__file__).resolve().parents[1] / "examples" / "paper_figures.py"
+    spec = importlib.util.spec_from_file_location("paper_figures", path)
+    figures = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(figures)
 
     def fig_fine():
         return ["fig_fine,1.0,ok"]
@@ -80,11 +87,10 @@ def test_bench_run_exits_nonzero_when_a_figure_fails(monkeypatch, capsys):
     def fig_broken():
         raise ValueError("boom")
 
-    monkeypatch.setattr(compile_cache, "use_compile_cache", lambda: None)
-    monkeypatch.setattr(fig_benchmarks, "ALL_FIGS", [fig_broken, fig_fine])
-    assert run.main([]) == 1
+    monkeypatch.setattr(figures, "ALL_FIGS", [fig_broken, fig_fine])
+    assert figures.main([]) == 1
     out = capsys.readouterr().out
     assert "fig_broken,0,ERROR:ValueError:boom" in out
     assert "# total wall" in out and "; 1 rows" in out   # fig_fine ran
-    monkeypatch.setattr(fig_benchmarks, "ALL_FIGS", [fig_fine])
-    assert run.main([]) == 0
+    monkeypatch.setattr(figures, "ALL_FIGS", [fig_fine])
+    assert figures.main([]) == 0

@@ -1,6 +1,6 @@
 """Workload generators: alltoall pair coverage and engine-level window
 semantics, permutation derangement properties, and the sparse/heavy-tailed
-generators feeding the leap benchmarks."""
+generators feeding the leap scenarios."""
 
 import numpy as np
 import pytest
